@@ -96,7 +96,7 @@ def paged_decode_case(B=64, H=32, KVH=8, D=128, page=64, NB=512):
 
     P = B * NB
     q = jax.ShapeDtypeStruct((B, H, D), jnp.bfloat16)
-    pages = jax.ShapeDtypeStruct((P, page, KVH, D), jnp.bfloat16)
+    pages = jax.ShapeDtypeStruct((P, KVH, page, D), jnp.bfloat16)
     tb = jax.ShapeDtypeStruct((B, NB), jnp.int32)
     ln = jax.ShapeDtypeStruct((B,), jnp.int32)
     oracle_bytes = _oracle_traffic(paged_decode_ref, q, pages, pages, tb, ln)

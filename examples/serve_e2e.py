@@ -1,5 +1,5 @@
-"""End-to-end serving driver (deliverable b): serve a REAL (reduced) model
-with batched requests through the full stack —
+"""End-to-end serving driver: serve a real (reduced) model with batched
+requests through the full stack —
 
     staged workload -> ServingEngine (two-stage pipeline on the runtime's
                        I/O executor; write-behind commits; off-path
@@ -12,109 +12,55 @@ with batched requests through the full stack —
 
 KV blocks written to / promoted from the disk tier are the model's actual
 cache tensors; TTFT here is fully measured (real compute + real I/O), and
-batch k+1's disk promotions run while batch k computes.
+batch k+1's disk promotions run while batch k computes.  The serving loop
+is ``repro.serving.real_model.serve_staged``, the one ``chip_smoke.py`` runs
+at published widths on a TPU.
 
     PYTHONPATH=src python examples/serve_e2e.py
 """
 
 import tempfile
-import time
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
-from repro.cache.hierarchy import CacheHierarchy
 from repro.configs import get_config
 from repro.core.sharded_store import ShardedKVBlockStore
-from repro.models import api
 from repro.runtime import RuntimeServices
-from repro.serving import ComputeModel, ServingEngine
-from repro.workload import StagedWorkload
+from repro.serving.real_model import RealModel, serve_staged
 
 ARCH = "qwen3-14b"
 BLOCK = 16
 PROMPT = 128
-DECODE_TOKENS = 8
 N_SHARDS = 4
-
-cfg = get_config(ARCH, smoke=True)
-params = api.init_params(cfg, jax.random.key(0))
-prefill = jax.jit(api.prefill_fn(cfg), static_argnames=())
-decode = jax.jit(api.decode_fn(cfg))
-
-kv_per_tok_elems = cfg.n_layers * 2 * cfg.n_kv_heads * cfg.d_head
-
-
-def real_prefill(tokens, reused):
-    """Run the real model over the non-reused suffix; return (blocks, secs).
-    Block i holds the bf16 KV slab for tokens [i*B, (i+1)*B)."""
-    t0 = time.perf_counter()
-    toks = jnp.asarray(tokens, jnp.int32)[None, :]
-    cache = api.init_cache(cfg, 1, len(tokens))
-    logits, cache = prefill(params, {"tokens": toks}, cache, 0)
-    jax.block_until_ready(logits)
-    dt = time.perf_counter() - t0
-    k, v = np.asarray(cache["k"], np.float32), np.asarray(cache["v"], np.float32)
-    nb = len(tokens) // BLOCK
-    start = reused // BLOCK
-    blocks = []
-    for i in range(start, nb):
-        sl = slice(i * BLOCK, (i + 1) * BLOCK)
-        blk = np.concatenate([k[:, 0, sl].reshape(BLOCK, -1, order="F"),
-                              v[:, 0, sl].reshape(BLOCK, -1, order="F")], axis=1)
-        blocks.append(blk.astype(np.float16))
-    return blocks, dt
 
 
 def main():
-    runtime = RuntimeServices(io_threads=4)
-    store = ShardedKVBlockStore(tempfile.mkdtemp(prefix="serve_e2e_"), n_shards=N_SHARDS,
-                                block_size=BLOCK, io_executor=runtime.executor)
-    h = CacheHierarchy(BLOCK, device_budget_blocks=64, host_budget_blocks=128, store=store)
-    eng = ServingEngine(h, ComputeModel(cfg), kv_bytes_per_token=kv_per_tok_elems * 2,
-                        max_batch_tokens=2048, real_prefill=real_prefill, runtime=runtime)
-
-    wl = StagedWorkload(prompt_len=PROMPT, requests_per_stage=6,
-                        stages=(0.0, 0.5, 0.75), block_size=BLOCK, corpus_size=8, seed=0)
-    print(f"serving {ARCH} (reduced) — real prefill, real disk tier")
-    # warmup: populate the corpus write-through (paper §4.1)
-    for p in wl.warmup_prompts(len(wl.corpus) * PROMPT):
-        eng.submit(type("R", (), {"tokens": p[:PROMPT], "rid": -1, "stage": -1})())
-    eng.run()
-    for si in range(len(wl.stages)):
-        recs = []
-        for r in wl.stage_requests(si):
-            eng.submit(r)
-        recs = eng.run()
-        hit = np.mean([r.reused_tokens / r.prompt_len for r in recs])
-        ttft = np.mean([r.ttft_s for r in recs])
-        print(f"stage {si} (expect hit {wl.stages[si]:.2f}): hit {hit:.2f}, "
-              f"TTFT {ttft*1e3:.1f}ms (io {np.mean([r.io_s for r in recs])*1e3:.1f}ms, "
-              f"wait {np.mean([r.io_wait_s for r in recs])*1e3:.1f}ms)")
-    eng.drain()  # settle write-behind + maintenance before the report
-
-    # a short decode to show the serve path end-to-end
-    toks = jnp.asarray(wl.corpus[0][:PROMPT], jnp.int32)[None, :]
-    cache = api.init_cache(cfg, 1, PROMPT + DECODE_TOKENS)
-    logits, cache = prefill(params, {"tokens": toks}, cache, 0)
-    out = []
-    last = jnp.argmax(logits[:, -1:], -1).astype(jnp.int32)
-    for i in range(DECODE_TOKENS):
-        logits, cache = decode(params, last, cache, PROMPT + i)
-        last = jnp.argmax(logits[:, -1:, :], -1).astype(jnp.int32)
-        out.append(int(last[0, 0]))
-    print(f"decoded {DECODE_TOKENS} tokens: {out}")
-    print(f"store: shards={store.n_shards} files/shard={store.shard_file_counts()} "
-          f"bytes={store.disk_bytes} compression={store.stats.compression_ratio:.2f}x "
-          f"hit-tiers d/h/d={h.stats.tokens_hit_device}/"
-          f"{h.stats.tokens_hit_host}/{h.stats.tokens_hit_disk}")
-    rep = eng.runtime_report()
-    print(f"runtime: prefetched={rep['prefetched_requests']} "
-          f"(ready on arrival {rep['prefetch_ready']}) overlap={rep['overlap_io_s']*1e3:.1f}ms "
-          f"writeback_blocks={rep['writeback_blocks']} "
-          f"maintenance_runs={rep['maintenance_runs']}")
-    eng.close()
+    model = RealModel(get_config(ARCH, smoke=True), BLOCK)
+    with RuntimeServices(io_threads=4) as runtime:
+        store = ShardedKVBlockStore(tempfile.mkdtemp(prefix="serve_e2e_"), n_shards=N_SHARDS,
+                                    block_size=BLOCK, io_executor=runtime.executor)
+        print(f"serving {ARCH} (reduced) — real prefill, real disk tier")
+        rep = serve_staged(model, store, runtime, prompt_len=PROMPT)
+        for si, st in enumerate(rep.stages):
+            print(f"stage {si} (expect hit {st.expected_hit:.2f}): hit {st.hit:.2f}, "
+                  f"TTFT {st.ttft_s*1e3:.1f}ms (io {st.io_s*1e3:.1f}ms, "
+                  f"compute {st.compute_s*1e3:.1f}ms)")
+        err = max(float(np.abs(s.astype(np.float32) - f.astype(np.float32)).max())
+                  for s, f in zip(rep.stored_blocks, rep.fresh_blocks))
+        print(f"hit prompt: {len(rep.stored_blocks)} blocks read back from the store, "
+              f"max |stored - fresh prefill| {err:.4g}")
+        print(f"decoded {len(rep.decoded)} tokens: {rep.decoded}")
+        print(f"compiles: warm-up {rep.warmup_compiles} ({rep.warmup_compile_s:.2f}s), "
+              f"after warm-up {rep.compiles_after_warmup}")
+        print(f"store: shards={store.n_shards} files/shard={store.shard_file_counts()} "
+              f"bytes={store.disk_bytes} compression={store.stats.compression_ratio:.2f}x "
+              f"hit-tiers d/h/d={rep.tokens_hit['device']}/{rep.tokens_hit['host']}/"
+              f"{rep.tokens_hit['disk']}")
+        r = rep.runtime
+        print(f"runtime: prefetched={r['prefetched_requests']} "
+              f"(ready on arrival {r['prefetch_ready']}) overlap={r['overlap_io_s']*1e3:.1f}ms "
+              f"writeback_blocks={r['writeback_blocks']} "
+              f"maintenance_runs={r['maintenance_runs']}")
     store.close()
     print("ok")
 

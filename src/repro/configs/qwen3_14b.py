@@ -12,5 +12,6 @@ CONFIG = ModelConfig(
     d_ff=17408,
     vocab_size=151936,
     qk_norm=True,
+    rope_theta=1_000_000.0,
     source="hf:Qwen/Qwen3-14B (family config per assignment)",
 )

@@ -90,6 +90,13 @@ def dequantize_int8(q: np.ndarray, scale: np.ndarray, dtype) -> np.ndarray:
     return (q.astype(np.float32) * scale.reshape((1,) * (q.ndim - 1) + (-1,))).astype(dtype)
 
 
+def int8_error_bound(x: np.ndarray) -> np.ndarray:
+    """Per-channel (last axis) bound on ``|x - int8 round trip of x|``: half
+    a quantization step, absmax/254, plus the rounding of x's own dtype."""
+    absmax = np.abs(x.astype(np.float32)).reshape(-1, x.shape[-1]).max(axis=0)
+    return absmax / 254 + absmax * np.finfo(x.dtype).eps + 1e-6
+
+
 def header_info(raw) -> Tuple[int, bool, Tuple[int, ...], int]:
     """Parse just the payload header: ``(codec, zlibbed, shape, dtype_code)``.
     Cheap (no body decode) — the tier recoder uses it to decide whether a

@@ -5,7 +5,10 @@ HBM pool; attention reads them through a block-table indirection.
 TPU adaptation of GPU paged attention: instead of warp-level gather, the
 page indirection lives in the BlockSpec ``index_map`` via scalar prefetch
 (``pltpu.PrefetchScalarGridSpec``) — the block table is prefetched to SMEM
-and each grid step DMAs exactly one (page x D) KV tile HBM->VMEM.  Online
+and each grid step DMAs exactly one (page x D) KV tile HBM->VMEM.  Pages
+are laid out (P, KVH, page, D), so that tile is the block's last two dims
+and meets the TPU's (8, 128) tiling for page % 8 == 0 and D % 128 == 0
+(a (page, 1, D) slice of a (page, KVH, D) page does not).  Online
 softmax state (m, l, acc) is carried in VMEM scratch across the sequential
 page axis; tiles are (G x page) and (page x D), MXU-friendly for G or page
 >= 8.  Pages past ``kv_len`` are masked; whole pages past the end are
@@ -44,8 +47,8 @@ def _kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref, acc, m, l, *, page
     @pl.when(run)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)  # (G, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # (page, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)  # (page, D)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale  # (G, page)
         pos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(pos < kv_len, s, NEG_INF)
@@ -63,10 +66,10 @@ def _kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref, acc, m, l, *, page
 
 
 def paged_decode_kernel(q, k_pages, v_pages, block_tables, kv_len, *, interpret: bool = False):
-    """q (B, KVH, G, D); k/v_pages (P, page, KVH, D); block_tables (B, NB);
+    """q (B, KVH, G, D); k/v_pages (P, KVH, page, D); block_tables (B, NB);
     kv_len (B,).  Returns (B, KVH, G, D)."""
     B, KVH, G, D = q.shape
-    P, page, _, _ = k_pages.shape
+    P, _, page, _ = k_pages.shape
     NB = block_tables.shape[1]
     grid = (B, KVH, NB)
 
@@ -74,7 +77,7 @@ def paged_decode_kernel(q, k_pages, v_pages, block_tables, kv_len, *, interpret:
         return (b, h, 0, 0)
 
     def kv_map(b, h, i, tables, lens):
-        return (tables[b, i], 0, h, 0)
+        return (tables[b, i], h, 0, 0)
 
     def o_map(b, h, i, tables, lens):
         return (b, h, 0, 0)
@@ -87,8 +90,8 @@ def paged_decode_kernel(q, k_pages, v_pages, block_tables, kv_len, *, interpret:
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, 1, G, D), q_map),
-                pl.BlockSpec((1, page, 1, D), kv_map),
-                pl.BlockSpec((1, page, 1, D), kv_map),
+                pl.BlockSpec((1, 1, page, D), kv_map),
+                pl.BlockSpec((1, 1, page, D), kv_map),
             ],
             out_specs=pl.BlockSpec((1, 1, G, D), o_map),
             scratch_shapes=[

@@ -13,15 +13,15 @@ F32 = jnp.float32
 
 
 def paged_decode_ref(q, k_pages, v_pages, block_tables, kv_len):
-    """q (B, H, D); k/v_pages (P, page, KVH, D); block_tables (B, NB) int32
+    """q (B, H, D); k/v_pages (P, KVH, page, D); block_tables (B, NB) int32
     page ids; kv_len (B,) valid tokens.  Returns (B, H, D)."""
     B, H, D = q.shape
-    P, page, KVH, _ = k_pages.shape
+    P, KVH, page, _ = k_pages.shape
     NB = block_tables.shape[1]
     G = H // KVH
     # gather pages -> (B, NB*page, KVH, D)
-    k = k_pages[block_tables].reshape(B, NB * page, KVH, D)
-    v = v_pages[block_tables].reshape(B, NB * page, KVH, D)
+    k = k_pages[block_tables].transpose(0, 1, 3, 2, 4).reshape(B, NB * page, KVH, D)
+    v = v_pages[block_tables].transpose(0, 1, 3, 2, 4).reshape(B, NB * page, KVH, D)
     T = NB * page
     qg = q.reshape(B, KVH, G, D).astype(F32)
     scores = jnp.einsum("bkgd,btkd->bkgt", qg, k.astype(F32)) / (D**0.5)

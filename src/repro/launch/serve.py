@@ -1,10 +1,12 @@
 """Serving launcher: ``python -m repro.launch.serve --arch <id>``.
 
 Runs the full serving stack — staged workload -> radix/LSM cache hierarchy
--> continuous-batching engine — with the disk tier on real files.  With
-``--real-model`` the prefill is executed for real on the reduced config
-(KV blocks come from the model's cache); otherwise compute is modeled and
-I/O measured (DESIGN.md §7).
+-> continuous-batching engine — with the disk tier on real files.  Compute
+is modelled (``serving/compute_model.py``: an A30 estimate) and I/O is
+measured; every block stored is one random template.  The path with real
+prefill, whose blocks are the model's own KV cache, is
+``repro.serving.real_model.serve_staged``, run by ``examples/serve_e2e.py``
+(smoke size) and ``chip_smoke.py`` (published widths, on a TPU).
 """
 
 import argparse

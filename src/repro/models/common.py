@@ -13,10 +13,7 @@ import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-try:  # non-deprecated home of thread_resources (jax >= 0.5)
-    from jax._src.mesh import thread_resources as _thread_resources
-except ImportError:  # pragma: no cover
-    from jax.interpreters.pxla import thread_resources as _thread_resources
+from jax._src.mesh import thread_resources as _thread_resources
 
 
 @dataclass(frozen=True)

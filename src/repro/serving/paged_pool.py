@@ -10,11 +10,12 @@ pool is a classic free-list allocator with per-sequence tables:
     stage(seq_id, page_idx, k_block, v_block)      host -> pool page
     block_tables(batch_of_seq_ids) -> (B, NB) int32 (padded)
 
-Pages are (page_size, KVH, Dh) per layer; the pool stores all layers of a
-page contiguously (L, page, KVH, Dh) so one promotion stages one object
-from the store.  Eviction is the hierarchy's concern — the pool refuses
-allocation when full (caller demotes and retries), keeping the allocator
-deterministic and thread-free like the rest of the runtime.
+Pages are (KVH, page_size, Dh) per layer, the layout the kernel tiles;
+the pool stores all layers of a page contiguously (L, KVH, page, Dh) so
+one promotion stages one object from the store.  Eviction is the
+hierarchy's concern — the pool refuses allocation when full (caller
+demotes and retries), keeping the allocator deterministic and
+thread-free like the rest of the runtime.
 """
 
 from __future__ import annotations
@@ -39,7 +40,7 @@ class PagedKVPool:
     dtype: np.dtype = np.dtype("float16")
 
     def __post_init__(self):
-        shape = (self.n_pages, self.n_layers, self.page_size, self.n_kv_heads, self.d_head)
+        shape = (self.n_pages, self.n_layers, self.n_kv_heads, self.page_size, self.d_head)
         self.k_pages = np.zeros(shape, self.dtype)
         self.v_pages = np.zeros(shape, self.dtype)
         self._free: List[int] = list(range(self.n_pages - 1, -1, -1))
@@ -83,8 +84,8 @@ class PagedKVPool:
         n_tok = k.shape[1]
         assert within + n_tok <= self.page_size, "block straddles a page"
         page = self._tables[seq_id][page_idx]
-        self.k_pages[page, :, within : within + n_tok] = k
-        self.v_pages[page, :, within : within + n_tok] = v
+        self.k_pages[page, :, :, within : within + n_tok] = k.transpose(0, 2, 1, 3)
+        self.v_pages[page, :, :, within : within + n_tok] = v.transpose(0, 2, 1, 3)
         self._lens[seq_id] = max(self._lens[seq_id], token_offset + n_tok)
 
     def append_token(self, seq_id: int, k: np.ndarray, v: np.ndarray) -> None:
@@ -113,5 +114,5 @@ class PagedKVPool:
         return np.asarray([self._lens[s] for s in seq_ids], np.int32)
 
     def layer_view(self, layer: int):
-        """(P, page, KVH, Dh) views for one layer — the kernel's operands."""
+        """(P, KVH, page, Dh) views for one layer — the kernel's operands."""
         return self.k_pages[:, layer], self.v_pages[:, layer]

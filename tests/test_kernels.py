@@ -61,8 +61,8 @@ def test_kv_codec_zero_channel_scale_one():
 def test_paged_decode_matches_oracle(B, H, KVH, D, page, NB, P, dtype):
     ks = jax.random.split(jax.random.fold_in(KEY, B * 1000 + H), 5)
     q = jax.random.normal(ks[0], (B, H, D), dtype)
-    kp = jax.random.normal(ks[1], (P, page, KVH, D), dtype)
-    vp = jax.random.normal(ks[2], (P, page, KVH, D), dtype)
+    kp = jax.random.normal(ks[1], (P, KVH, page, D), dtype)
+    vp = jax.random.normal(ks[2], (P, KVH, page, D), dtype)
     tables = jax.random.randint(ks[3], (B, NB), 0, P)
     kv_len = jax.random.randint(ks[4], (B,), 1, NB * page + 1)
     out = paged_decode(q, kp, vp, tables, kv_len, interpret=True)
@@ -78,12 +78,12 @@ def test_paged_decode_single_valid_token():
     B, H, KVH, D, page, NB, P = 1, 2, 1, 64, 8, 2, 4
     ks = jax.random.split(KEY, 4)
     q = jax.random.normal(ks[0], (B, H, D), jnp.float32)
-    kp = jax.random.normal(ks[1], (P, page, KVH, D), jnp.float32)
-    vp = jax.random.normal(ks[2], (P, page, KVH, D), jnp.float32)
+    kp = jax.random.normal(ks[1], (P, KVH, page, D), jnp.float32)
+    vp = jax.random.normal(ks[2], (P, KVH, page, D), jnp.float32)
     tables = jnp.array([[2, 0]], jnp.int32)
     kv_len = jnp.array([1], jnp.int32)
     out = paged_decode(q, kp, vp, tables, kv_len, interpret=True)
-    # attention over one token == that token's value
+    # attention over one token == that token's value (page 2, head 0, slot 0)
     np.testing.assert_allclose(
         np.asarray(out)[0, 0], np.asarray(vp)[2, 0, 0], rtol=1e-5, atol=1e-5
     )

@@ -1,14 +1,21 @@
 """Serving engine + workload: staged hit rates realized, TTFT accounting,
 hedged reads, LSM-vs-baseline ordering on a miniature workload."""
 
+import os
+import subprocess
+import sys
+
 import numpy as np
 import pytest
 
 from repro.cache.hierarchy import CacheHierarchy
 from repro.configs import get_config
 from repro.core.baselines import FilePerObjectStore, MemoryOnlyStore
+from repro.core.codec import CODEC_INT8, CODEC_RAW, BatchCodec, int8_error_bound
 from repro.core.store import KVBlockStore
+from repro.runtime import RuntimeServices
 from repro.serving import ComputeModel, ServingEngine
+from repro.serving.real_model import RealModel, serve_staged
 from repro.workload import PAPER_STAGES, StagedWorkload
 
 
@@ -109,3 +116,60 @@ def test_hedged_read_retries_straggler(tmp_path):
     assert calls["n"] == 2
     assert eng.stats.hedged_reads == 1
     assert dt < 0.02  # the retry won
+
+
+# --------------------------------------- real-model serving loop (smoke size)
+@pytest.fixture(scope="module")
+def smoke_model():
+    return RealModel(get_config("qwen3-14b", smoke=True), block_size=16)
+
+
+def _serve_real(model, store, **sizes):
+    with RuntimeServices(io_threads=2) as runtime:
+        rep = serve_staged(model, store, runtime, prompt_len=128, **sizes)
+    store.close()
+    return rep
+
+
+def test_real_model_stage_hit_rates_meet_expectation(tmp_path, smoke_model):
+    rep = _serve_real(smoke_model, KVBlockStore(str(tmp_path / "lsm"), block_size=16))
+    assert [st.expected_hit for st in rep.stages] == [0.0, 0.5, 0.75]
+    for st in rep.stages:
+        assert abs(st.hit - st.expected_hit) <= 0.05
+        assert st.compute_s > 0
+    assert rep.compiles_after_warmup == 0
+    assert len(rep.decoded) == 8
+    assert np.isfinite(np.asarray(rep.logits, np.float32)).all()
+
+
+@pytest.mark.parametrize("codec", ["raw", "int8-zlib"])
+def test_blocks_read_back_at_a_disk_hit_match_a_fresh_prefill(tmp_path, smoke_model, codec):
+    """Budgets far below the corpus push hits to disk; what the store
+    returns for a hit prompt is the prefill's own blocks — bit for bit
+    through the raw codec, within the int8 bound through the default."""
+    if codec == "raw":
+        bc = BatchCodec(CODEC_RAW, use_zlib=False)
+    else:
+        bc = BatchCodec(CODEC_INT8, use_zlib=True)
+    store = KVBlockStore(str(tmp_path / "lsm"), block_size=16, codec=bc)
+    rep = _serve_real(smoke_model, store, device_blocks=4, host_blocks=8)
+    assert rep.tokens_hit["disk"] > 0
+    assert len(rep.stored_blocks) == len(rep.fresh_blocks) == 128 // 16
+    for s, f in zip(rep.stored_blocks, rep.fresh_blocks):
+        assert s.dtype == f.dtype == np.float16 and s.shape == f.shape
+        if codec == "raw":
+            np.testing.assert_array_equal(s.view(np.uint16), f.view(np.uint16))
+        else:
+            err = np.abs(s.astype(np.float32) - f.astype(np.float32))
+            assert (err <= int8_error_bound(f)).all()
+
+
+def test_cluster_node_imports_no_jax():
+    """Node processes must not load JAX: the serving process alone holds
+    the chip, and a child that touched it would fail or hang."""
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = dict(os.environ, PYTHONPATH=src)
+    proc = subprocess.run(
+        [sys.executable, "-c", "import repro.cluster.node, sys; assert 'jax' not in sys.modules"],
+        env=env, timeout=60)
+    assert proc.returncode == 0
