@@ -1,0 +1,14 @@
+"""Mean seconds of the copy of the K/V cache from the device to the host, in
+its device dtype: the program's ``device_to_host`` span in
+``RealModel.__call__`` (``RequestRecord.spans``), over the window's requests
+that ran a prefill.  None where the program records no such span.
+
+The same reading as ``model.device_to_host_s``, in the cells whose end-to-end
+TTFT is the tail (``ttft_p90_s``).
+"""
+
+
+def read(w):
+    got = [s["device_to_host"] for s in (getattr(r, "spans", None) for r in w.records)
+           if s and "device_to_host" in s]
+    return sum(got) / len(got) if got else None

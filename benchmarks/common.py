@@ -137,8 +137,7 @@ def run_staged(engine: ServingEngine, s: BenchScale, seed: int = 0) -> List[Stag
     for p in wl.warmup_prompts(warm):
         engine.submit(type("R", (), {"tokens": p, "rid": -1, "stage": -1})())
     engine.run()
-    engine.stats.ttfts.clear()
-    engine.stats.hits.clear()
+    engine.stats.reset_means()
 
     out: List[StageResult] = []
     for si in range(len(s.stages)):

@@ -105,8 +105,7 @@ def engine_compare(
                 eng.submit(type("R", (), {"tokens": p, "rid": -1, "stage": -1})())
             eng.run()
             eng.drain()
-            eng.stats.ttfts.clear()
-            eng.stats.hits.clear()
+            eng.stats.reset_means()
             recs = []
             for si in range(len(stages)):
                 for r in wl.stage_requests(si):
@@ -181,8 +180,7 @@ def tracing_overhead(
                 eng.submit(type("R", (), {"tokens": p, "rid": -1, "stage": -1})())
             eng.run()
             eng.drain()
-            eng.stats.ttfts.clear()
-            eng.stats.hits.clear()
+            eng.stats.reset_means()
             recs = []
             for si in range(len(stages)):
                 for r in wl.stage_requests(si):

@@ -453,7 +453,8 @@ class CacheHierarchy:
         n_new = min(len(new_blocks), total_blocks - start_block)
         if n_new <= 0:
             return
-        self._make_room(TIER_DEVICE, n_new)
+        with maybe_span("make_room"):
+            self._make_room(TIER_DEVICE, n_new)
         path = self.tree.insert_path(tokens[: (start_block + n_new) * B])
         fresh = path[start_block:]
         for n, blk in zip(fresh, new_blocks):
@@ -479,10 +480,11 @@ class CacheHierarchy:
                 store = self.store
                 for n in fresh:
                     n.on_disk = True
-                self.commit_queue.submit(
-                    lambda: store.put_batch(toks, blocks, start_block=start_block),
-                    nbytes=sum(b.nbytes for b in blocks),
-                )
+                with maybe_span("enqueue"):  # blocks while the queue is full
+                    self.commit_queue.submit(
+                        lambda: store.put_batch(toks, blocks, start_block=start_block),
+                        nbytes=sum(b.nbytes for b in blocks),
+                    )
                 self.stats.writeback_blocks += n_new
             else:
                 self.store.put_batch(tokens, new_blocks[:n_new], start_block=start_block)

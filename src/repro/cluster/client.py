@@ -63,7 +63,7 @@ from typing import Iterator, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ..core.store import StoreStats
-from ..obs.tracing import current_trace
+from ..obs.tracing import wire_trace_id
 from . import protocol as P
 from .mux import MuxConnection, MuxLoop, _StreamWaiter, _UnaryWaiter
 from .server import Address
@@ -224,10 +224,8 @@ class RemoteKVBlockStore:
         conn = self._conn()
         waiter = _UnaryWaiter()
         rid = conn.attach(waiter)
-        tr = current_trace()
         try:
-            sent = conn.send_request(rid, request,
-                                     trace=tr.id_bytes() if tr else None)
+            sent = conn.send_request(rid, request, trace=wire_trace_id())
             payload = waiter.wait(self.timeout_s)
         finally:
             conn.detach(rid)  # never leak a waiter, success or not
@@ -285,10 +283,8 @@ class RemoteKVBlockStore:
                 conn = self._conn()
                 waiter = _StreamWaiter()
                 rid = conn.attach(waiter)
-                tr = current_trace()
                 try:
-                    sent = conn.send_request(rid, request,
-                                             trace=tr.id_bytes() if tr else None)
+                    sent = conn.send_request(rid, request, trace=wire_trace_id())
                     with self._lock:
                         self.rpc_stats.streams += 1
                         self.rpc_stats.bytes_sent += sent

@@ -40,6 +40,7 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from ..obs.tracing import maybe_span
 from .batchops import BatchOpsMixin
 from .codec import CODEC_INT8, BatchCodec
 from .controller import OP_EMPTY, OP_RANGE, OP_READ, OP_WRITE, AdaptiveController
@@ -248,20 +249,21 @@ class KVBlockStore(BatchOpsMixin):
         # across shards sharing a thread pool.  The dedup check may race a
         # concurrent writer of the same key; the loser's record becomes
         # garbage the merge service collects — never a lost write.
-        for i, block in enumerate(blocks):
-            bi = start_block + i
-            end = (bi + 1) * B
-            if end > len(tokens):
-                break
-            key = self._key(tokens, end)
-            if skip_existing:
-                found, _ = self.index.get(key)
-                if found:
-                    continue
-            payload = self.codec.encode(np.asarray(block))
-            bytes_in += np.asarray(block).nbytes
-            bytes_stored += len(payload)
-            records.append((key, payload))
+        with maybe_span("encode"):
+            for i, block in enumerate(blocks):
+                bi = start_block + i
+                end = (bi + 1) * B
+                if end > len(tokens):
+                    break
+                key = self._key(tokens, end)
+                if skip_existing:
+                    found, _ = self.index.get(key)
+                    if found:
+                        continue
+                payload = self.codec.encode(np.asarray(block))
+                bytes_in += np.asarray(block).nbytes
+                bytes_stored += len(payload)
+                records.append((key, payload))
         if not records:
             return 0
         with self._lock:
@@ -291,6 +293,10 @@ class KVBlockStore(BatchOpsMixin):
         """Longest cached prefix length in tokens (multiple of block_size).
         Binary search over block counts; each step is an LSM point lookup
         (paper App. B: Bloom filters prune the misses)."""
+        with maybe_span("probe"):
+            return self._probe(tokens)
+
+    def _probe(self, tokens: Sequence[int]) -> int:
         B = self.block_size
         max_blocks = len(tokens) // B
         with self._stats_lock:
@@ -379,11 +385,13 @@ class KVBlockStore(BatchOpsMixin):
             if not present:
                 break
             try:
-                recs = self.log.read_batch([p for _, p in present])
+                with maybe_span("read"):
+                    recs = self.log.read_batch([p for _, p in present])
             except FileNotFoundError:
                 continue  # lost the race with eviction/merge: retry
-            for (i, _), (_, payload) in zip(present, recs):
-                blocks[i] = BatchCodec.decode(payload)
+            with maybe_span("decode"):
+                for (i, _), (_, payload) in zip(present, recs):
+                    blocks[i] = BatchCodec.decode(payload)
             break
         # only the contiguous prefix is usable as KV cache
         out: List[np.ndarray] = []

@@ -25,7 +25,8 @@ from .tracing import ENGINE_SPANS
 def _numeric_fields(cls) -> List[str]:
     out = []
     for f in dataclasses.fields(cls):
-        if f.type in ("int", "float", int, float):
+        # underscored fields are private sums, skipped by dataclass_gauges
+        if f.type in ("int", "float", int, float) and not f.name.startswith("_"):
             out.append(f.name)
     return out
 

@@ -8,6 +8,11 @@ Request lifecycle (paper Fig. 6):
 Production concerns implemented here:
   * continuous batching with a token budget per engine step,
   * TTFT accounting split into measured I/O + (modeled or real) compute,
+  * a timeline per request, always on: a ``TraceContext`` whose span
+    totals the record carries, and stamps at submit, at the start and end
+    of its own service and at the return of its ``step()`` (``queue_s``,
+    ``batch_wait_s``); ``tracing=True`` adds the span histograms and sends
+    the trace id to cluster nodes,
   * a two-stage pipeline (``runtime=RuntimeServices(...)``): while batch k
     is being served, batch k+1's disk fetches (probe + batched get) are
     already running on the I/O executor — ``hierarchy.plan`` on the engine
@@ -38,7 +43,6 @@ from collections import deque
 from concurrent.futures import FIRST_COMPLETED
 from concurrent.futures import TimeoutError as FutureTimeoutError
 from concurrent.futures import wait as futures_wait
-from contextlib import nullcontext
 from dataclasses import dataclass, field
 from typing import Callable, Deque, Dict, List, Optional, Sequence, Tuple
 
@@ -60,10 +64,21 @@ class RequestRecord:
     io_wait_s: float = 0.0  # non-overlapped wait on the prefetch future
     first_block_s: Optional[float] = None  # streamed fetch: time-to-first-block
     compute_s: float = 0.0
+    # the charged I/O plus compute (io_s + compute_s); what the client
+    # waits is queue_s + its service + batch_wait_s
     ttft_s: float = 0.0
     hedged: bool = False
     prefetched: bool = False
     stage: int = -1
+    # from submit to the start of its own service: the deque, and being
+    # staged one step ahead by the pipeline
+    queue_s: float = 0.0
+    # from the end of its own service to the return of its step(): the
+    # rest of its batch, and maintenance
+    batch_wait_s: float = 0.0
+    # seconds per span name on its trace (``obs.tracing.ENGINE_SPANS``);
+    # service is io_s + spans["compute"] + spans["commit"]
+    spans: Dict[str, float] = field(default_factory=dict)
 
 
 @dataclass
@@ -83,23 +98,33 @@ class EngineStats:
     io_wait_s: float = 0.0  # I/O the pipeline could NOT hide (charged to TTFT)
     overlap_io_s: float = 0.0  # I/O executed under the previous batch's service
 
-    ttfts: List[float] = field(default_factory=list)
-    ttfbs: List[float] = field(default_factory=list)  # streamed fetches only
-    hits: List[float] = field(default_factory=list)
+    streamed_fetches: int = 0  # fetches that streamed their blocks
+    # running sums behind the mean gauges (underscored: not gauges themselves)
+    _ttft_sum_s: float = 0.0  # submit to step return
+    _hit_sum: float = 0.0  # reused share of the prompt
+    _means_n: int = 0  # requests in the two sums above
+    _ttfb_sum_s: float = 0.0  # over streamed_fetches
 
     @property
     def mean_ttft(self) -> float:
-        return float(np.mean(self.ttfts)) if self.ttfts else 0.0
+        """Mean TTFT as a client sees it: submit to the return of the
+        ``step()`` that returned the request."""
+        return self._ttft_sum_s / self._means_n if self._means_n else 0.0
 
     @property
     def mean_ttfb(self) -> float:
         """Mean time-to-first-block across fetches that streamed — the
         latency at which the pipeline starts installing disk state."""
-        return float(np.mean(self.ttfbs)) if self.ttfbs else 0.0
+        return self._ttfb_sum_s / self.streamed_fetches if self.streamed_fetches else 0.0
 
     @property
     def mean_hit(self) -> float:
-        return float(np.mean(self.hits)) if self.hits else 0.0
+        return self._hit_sum / self._means_n if self._means_n else 0.0
+
+    def reset_means(self) -> None:
+        """Start the mean TTFT and hit gauges afresh (after a warm-up)."""
+        self._ttft_sum_s = self._hit_sum = 0.0
+        self._means_n = 0
 
 
 @dataclass
@@ -110,7 +135,8 @@ class _Staged:
     req: object
     plan: Optional[AcquirePlan]
     future: Optional[object] = None  # Future[DiskFetch] when prefetched
-    trace: Optional[TraceContext] = None  # per-request trace (tracing=True)
+    trace: Optional[TraceContext] = None  # the request's trace (``_stage`` sets it)
+    submitted: float = 0.0  # perf_counter at submit
 
 
 class ServingEngine:
@@ -179,7 +205,6 @@ class ServingEngine:
                 "repro_engine_mean_ttft_s": self.stats.mean_ttft,
                 "repro_engine_mean_ttfb_s": self.stats.mean_ttfb,
                 "repro_engine_mean_hit": self.stats.mean_hit,
-                "repro_engine_streamed_fetches": float(len(self.stats.ttfbs)),
             }))
         reg.register_collector(dataclass_gauges("repro_cache", self.h.stats))
         if self.runtime is not None:
@@ -195,7 +220,7 @@ class ServingEngine:
 
     # ------------------------------------------------------------ lifecycle
     def submit(self, request) -> None:
-        self._queue.append(request)
+        self._queue.append((request, time.perf_counter()))
 
     def run(self) -> List[RequestRecord]:
         out = []
@@ -216,29 +241,32 @@ class ServingEngine:
             self.runtime.close()
 
     # ------------------------------------------------------- batch formation
-    def _form_batch(self) -> List:
+    def _form_batch(self) -> List[Tuple[object, float]]:
+        """(request, submit time) pairs within the batch's token budget."""
         batch, tokens = [], 0
-        while self._queue and tokens + len(self._queue[0].tokens) <= self.max_batch_tokens:
-            r = self._queue.popleft()
-            batch.append(r)
+        while self._queue and tokens + len(self._queue[0][0].tokens) <= self.max_batch_tokens:
+            r, t = self._queue.popleft()
+            batch.append((r, t))
             tokens += len(r.tokens)
         if not batch and self._queue:  # oversized single request
             batch.append(self._queue.popleft())
         return batch
 
-    def _stage(self, batch: List, prefetch: bool) -> List[_Staged]:
+    def _stage(self, batch: List[Tuple[object, float]], prefetch: bool) -> List[_Staged]:
         """Phase 1 for every request (engine thread); optionally launch
         phase 2 on the executor (prefetch-ahead)."""
         staged = []
         ex = self.runtime.executor if self.runtime is not None else None
-        for r in batch:
-            trace = TraceContext() if self.tracing else None
+        for r, submitted in batch:
+            # every request carries its timeline; only tracing=True sends
+            # the id to cluster nodes
+            trace = TraceContext(on_wire=self.tracing)
             if ex is None:
                 # no runtime: the legacy acquire path re-plans internally,
                 # so planning here would walk the radix tree twice
-                staged.append(_Staged(req=r, plan=None, trace=trace))
+                staged.append(_Staged(req=r, plan=None, trace=trace, submitted=submitted))
                 continue
-            with activate(trace) if trace is not None else nullcontext():
+            with activate(trace):
                 plan = self.h.plan(r.tokens)
                 fut = None
                 # never stall the engine thread on the admission gate: if
@@ -252,7 +280,8 @@ class ServingEngine:
                     fut = ex.try_submit(self.h.fetch, plan)
                     if fut is not None:
                         self.stats.prefetched_requests += 1
-            staged.append(_Staged(req=r, plan=plan, future=fut, trace=trace))
+            staged.append(_Staged(req=r, plan=plan, future=fut, trace=trace,
+                                  submitted=submitted))
         return staged
 
     def step(self) -> List[RequestRecord]:
@@ -260,6 +289,21 @@ class ServingEngine:
         serve it, run maintenance.  Pipelined mode: serve the batch whose
         fetches were launched last step, while this step launches the
         fetches of the next one."""
+        with maybe_span("step"):
+            served = self._step()
+            t_return = time.perf_counter()
+        records = []
+        for st, rec, t_served in served:
+            rec.batch_wait_s = t_return - t_served
+            ttft = t_return - st.submitted
+            self._h_ttft.observe(ttft)
+            self.stats._ttft_sum_s += ttft
+            self.stats._hit_sum += rec.reused_tokens / max(1, rec.prompt_len)
+            self.stats._means_n += 1
+            records.append(rec)
+        return records
+
+    def _step(self) -> List[Tuple[_Staged, RequestRecord, float]]:
         can_prefetch = self.pipeline and self.runtime is not None and self.runtime.async_mode
         if self._staged is not None:
             current = self._staged
@@ -273,7 +317,7 @@ class ServingEngine:
         if can_prefetch:
             nxt = self._stage(self._form_batch(), prefetch=True)
             self._staged = nxt or None
-        records = [self._serve_one(s) for s in current]
+        served = [self._serve_one(s) for s in current]
         self._batches += 1
         if self._batches % self.maintenance_every == 0:
             if self._maintenance is not None and self.runtime.async_mode:
@@ -286,7 +330,7 @@ class ServingEngine:
                     self.stats.maintenance_compactions += int(rep.get("compactions", 0) or 0)
                     self.stats.evicted_files += int(rep.get("evicted_files", 0) or 0)
         self._harvest_maintenance()
-        return records
+        return served
 
     def _harvest_maintenance(self) -> None:
         if self._maintenance is None:
@@ -329,28 +373,31 @@ class ServingEngine:
         (fetch, wait_seconds, hedged)."""
         ex = self.runtime.executor
         fut = st.future
-        if fut is None:
-            if not st.plan.need_disk:
-                return DiskFetch(), 0.0, False
-            fut = ex.submit(self.h.fetch, st.plan)
-        elif fut.done():
+        if fut is None and not st.plan.need_disk:
+            return DiskFetch(), 0.0, False
+        if fut is not None and fut.done():
             self.stats.prefetch_ready += 1
         t0 = time.perf_counter()
         hedged = False
-        timeout = self.hedge_factor * self._ewma_read_s if self._ewma_read_s > 0 else None
-        try:
-            fetched = fut.result(timeout=timeout)
-        except FutureTimeoutError:
-            # straggler: hedge on the executor; first finished attempt wins
-            hedge = ex.submit(self.h.fetch, st.plan)
-            self.stats.hedged_reads += 1
-            self.stats.redispatches += 1
-            hedged = True
-            pending = {fut, hedge}
-            done = set()
-            while not done:
-                done, pending = futures_wait(pending, timeout=1.0, return_when=FIRST_COMPLETED)
-            fetched = next(iter(done)).result()
+        with maybe_span("fetch_wait"):
+            if fut is None:
+                # admission may block, and an inline executor runs the fetch here
+                fut = ex.submit(self.h.fetch, st.plan)
+            timeout = self.hedge_factor * self._ewma_read_s if self._ewma_read_s > 0 else None
+            try:
+                fetched = fut.result(timeout=timeout)
+            except FutureTimeoutError:
+                # straggler: hedge on the executor; first finished attempt wins
+                hedge = ex.submit(self.h.fetch, st.plan)
+                self.stats.hedged_reads += 1
+                self.stats.redispatches += 1
+                hedged = True
+                pending = {fut, hedge}
+                done = set()
+                while not done:
+                    done, pending = futures_wait(pending, timeout=1.0,
+                                                 return_when=FIRST_COMPLETED)
+                fetched = next(iter(done)).result()
         wait_s = time.perf_counter() - t0
         if fetched.io_s > 0:
             self._ewma_read_s = (
@@ -358,18 +405,23 @@ class ServingEngine:
             )
         return fetched, wait_s, hedged
 
-    def _serve_one(self, st: _Staged) -> RequestRecord:
-        with activate(st.trace) if st.trace is not None else nullcontext():
+    def _serve_one(self, st: _Staged) -> Tuple[_Staged, RequestRecord, float]:
+        """Serve one staged request; returns it, its record and the time
+        its own service ended."""
+        with activate(st.trace):
+            t_start = time.perf_counter()
             rec = self._serve(st)
-        self._h_ttft.observe(rec.ttft_s)
+            t_served = time.perf_counter()
+        rec.queue_s = t_start - st.submitted
+        rec.spans = st.trace.span_totals()
         self._h_io_wait.observe(rec.io_wait_s)
-        if st.trace is not None:
+        if self.tracing:
             # one histogram per span name: the engine-side closure of the
             # trace, matching the node-side close-out in the server
-            for name, total in st.trace.span_totals().items():
+            for name, total in rec.spans.items():
                 self.registry.histogram(
                     f"repro_engine_span_seconds_{name}").observe(total)
-        return rec
+        return st, rec, t_served
 
     def _serve(self, st: _Staged) -> RequestRecord:
         req = st.req
@@ -428,10 +480,9 @@ class ServingEngine:
             stage=getattr(req, "stage", -1),
         )
         self.stats.completed += 1
-        self.stats.ttfts.append(rec.ttft_s)
         if first_block_s is not None:
-            self.stats.ttfbs.append(first_block_s)
-        self.stats.hits.append(reused / max(1, len(tokens)))
+            self.stats.streamed_fetches += 1
+            self.stats._ttfb_sum_s += first_block_s
         return rec
 
     # ---------------------------------------------------------------- report

@@ -34,6 +34,7 @@ import numpy as np
 from ..cache.hierarchy import AcquirePlan, CacheHierarchy
 from ..configs.base import ModelConfig
 from ..models import api
+from ..obs.tracing import maybe_span
 from ..runtime import RuntimeServices
 from ..workload import Request, StagedWorkload
 from .compute_model import ComputeModel
@@ -121,16 +122,20 @@ class RealModel:
     def __call__(self, tokens: Sequence[int], reused: int) -> Tuple[List[np.ndarray], float]:
         """Prefill the whole prompt; return the float16 blocks past the
         ``reused`` prefix and the seconds the prefill took on the device."""
-        t0 = time.perf_counter()
-        logits, cache = self._prefill_into(tokens, len(tokens))
-        jax.block_until_ready((logits, cache))
-        dt = time.perf_counter() - t0
-        B = self.block_size
-        kv = np.concatenate([np.asarray(cache["k"], np.float32)[:, 0],
-                             np.asarray(cache["v"], np.float32)[:, 0]])  # (2L, S, KVH, Dh)
-        rows = kv.transpose(1, 0, 2, 3).reshape(kv.shape[1], -1)
-        blocks = [rows[i * B:(i + 1) * B].astype(np.float16)
-                  for i in range(reused // B, len(tokens) // B)]
+        with maybe_span("prefill"):
+            t0 = time.perf_counter()
+            logits, cache = self._prefill_into(tokens, len(tokens))
+            jax.block_until_ready((logits, cache))
+            dt = time.perf_counter() - t0
+        with maybe_span("device_to_host"):  # the transfer alone, in the cache's dtype
+            k, v = np.asarray(cache["k"]), np.asarray(cache["v"])
+        with maybe_span("to_blocks"):
+            B = self.block_size
+            kv = np.concatenate([k.astype(np.float32)[:, 0],
+                                 v.astype(np.float32)[:, 0]])  # (2L, S, KVH, Dh)
+            rows = kv.transpose(1, 0, 2, 3).reshape(kv.shape[1], -1)
+            blocks = [rows[i * B:(i + 1) * B].astype(np.float16)
+                      for i in range(reused // B, len(tokens) // B)]
         return blocks, dt
 
     def generate(self, tokens: Sequence[int], n_new: int) -> Tuple[List[int], jax.Array]:

@@ -270,3 +270,114 @@ def test_scrape_cluster_reports_dead_node_unreachable(tmp_path):
         store.close()
         for n in nodes:
             n.close()
+
+
+# ------------------------------------------ the engine's always-on timeline
+def test_obs_imports_without_jax():
+    """Cache-node processes import repro.obs: it must not load JAX, and
+    maybe_span must work there with no profiler to write to."""
+    import os
+    import subprocess
+    import sys
+
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    code = ("import sys, repro.obs as o\n"
+            "t = o.TraceContext()\n"
+            "with o.activate(t):\n"
+            "    with o.maybe_span('probe'):\n"
+            "        pass\n"
+            "assert set(t.span_totals()) == {'probe'}\n"
+            "assert 'jax' not in sys.modules\n")
+    proc = subprocess.run([sys.executable, "-c", code], env=dict(os.environ, PYTHONPATH=src),
+                          timeout=60)
+    assert proc.returncode == 0
+
+
+def test_trace_on_wire_flag_gates_the_wire_id():
+    from repro.obs.tracing import wire_trace_id
+
+    assert wire_trace_id() is None
+    tr = TraceContext()
+    with activate(tr):
+        assert wire_trace_id() == tr.id_bytes()
+    with activate(TraceContext(on_wire=False)):
+        assert wire_trace_id() is None
+
+
+def _staged_engine(store, block_size: int, tracing: bool, runtime=None):
+    from repro.cache.hierarchy import CacheHierarchy
+    from repro.configs import get_config
+    from repro.serving import ComputeModel, ServingEngine
+
+    h = CacheHierarchy(block_size, 4, 8, store=store)
+    return ServingEngine(h, ComputeModel(get_config("glm4-9b")), kv_bytes_per_token=64,
+                         max_batch_tokens=256, runtime=runtime, tracing=tracing)
+
+
+def _serve_twice(eng, prompts):
+    """Serve the prompts, settle the writes, then serve them again: the
+    second round's hits come from the store (the tiers hold 12 blocks)."""
+    for _ in range(2):
+        for p in prompts:
+            eng.submit(type("R", (), {"tokens": p, "rid": -1, "stage": -1})())
+        recs = eng.run()
+        eng.drain()
+    return recs
+
+
+def test_untraced_engine_records_store_decode_on_an_io_worker(tmp_path, monkeypatch):
+    """With tracing off, each record still carries its spans, the store's
+    decode among them, taken on an I/O worker under the request's trace;
+    no span histogram is observed."""
+    import numpy as np
+
+    from repro.core.store import KVBlockStore
+    from repro.runtime import RuntimeServices
+
+    threads = []
+    orig = KVBlockStore.get_batch
+
+    def get_batch(self, tokens, n_tokens):
+        threads.append(threading.current_thread())
+        return orig(self, tokens, n_tokens)
+
+    monkeypatch.setattr(KVBlockStore, "get_batch", get_batch)
+    rng = np.random.default_rng(3)
+    prompts = [[int(x) for x in rng.integers(0, 50_000, 64)] for _ in range(6)]
+    store = KVBlockStore(str(tmp_path / "lsm"), block_size=16)
+    with RuntimeServices(io_threads=2) as runtime:
+        eng = _staged_engine(store, 16, tracing=False, runtime=runtime)
+        recs = _serve_twice(eng, prompts)
+        snap = eng.metrics_snapshot()
+    store.close()
+    decoded = [r for r in recs if "decode" in r.spans]
+    assert decoded and threads
+    assert threading.main_thread() not in threads
+    for r in decoded:
+        assert {"read", "fetch", "fetch_wait"} <= set(r.spans)
+        assert r.spans["decode"] > 0
+    assert not [k for k in snap["histograms"] if k.startswith("repro_engine_span_seconds_")]
+
+
+@pytest.mark.parametrize("tracing", [False, True])
+def test_engine_sends_trace_ids_to_nodes_only_when_tracing(tracing):
+    import numpy as np
+    from cluster_harness import B, close_all, mem_cluster
+
+    servers, cluster = mem_cluster(1, 1)
+    try:
+        rng = np.random.default_rng(4)
+        prompts = [[int(x) for x in rng.integers(0, 50_000, 8 * B)] for _ in range(4)]
+        eng = _staged_engine(cluster, B, tracing=tracing)
+        recs = _serve_twice(eng, prompts)
+        assert any(r.reused_tokens for r in recs)
+        traced = servers[0].registry.snapshot()["counters"].get(
+            "repro_node_trace_requests_total", 0)
+        spans = [k for k in eng.metrics_snapshot()["histograms"]
+                 if k.startswith("repro_engine_span_seconds_")]
+        if tracing:
+            assert traced > 0 and spans
+        else:
+            assert traced == 0 and not spans
+    finally:
+        close_all(cluster, servers)

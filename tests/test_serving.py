@@ -4,6 +4,7 @@ hedged reads, LSM-vs-baseline ordering on a miniature workload."""
 import os
 import subprocess
 import sys
+import time
 
 import numpy as np
 import pytest
@@ -173,3 +174,122 @@ def test_cluster_node_imports_no_jax():
         [sys.executable, "-c", "import repro.cluster.node, sys; assert 'jax' not in sys.modules"],
         env=env, timeout=60)
     assert proc.returncode == 0
+
+
+# ------------------------------------------- the per-request timeline
+def _staged_requests(seed: int, stages=(0.0, 0.9)):
+    wl = StagedWorkload(prompt_len=128, requests_per_stage=6, stages=stages, block_size=16,
+                        corpus_size=4, seed=seed)
+    return wl, [r for si in range(len(stages)) for r in wl.stage_requests(si)]
+
+
+def test_pipelined_record_parts_add_up_to_step_return_minus_submit(tmp_path, smoke_model):
+    """queue_s + io_s + compute + commit + batch_wait_s is what a client
+    waits: submit to the return of the step() that returned the request."""
+    smoke_model.warmup(128, 0)
+    store = KVBlockStore(str(tmp_path / "lsm"), block_size=16)
+    with RuntimeServices(io_threads=2) as runtime:
+        h = CacheHierarchy(16, 8, 16, store=store)
+        eng = ServingEngine(h, ComputeModel(smoke_model.cfg), kv_bytes_per_token=512,
+                            max_batch_tokens=512, real_prefill=smoke_model, runtime=runtime)
+        assert eng.pipeline
+        wl, reqs = _staged_requests(seed=8)
+        for p in wl.corpus:
+            eng.submit(type("R", (), {"tokens": p, "rid": -1, "stage": -1})())
+        eng.run()
+        eng.drain()
+        eng.stats.reset_means()
+        submitted, waited = {}, {}
+        for r in reqs:
+            submitted[r.rid] = time.perf_counter()
+            eng.submit(r)
+        while len(waited) < len(reqs):
+            recs = eng.step()
+            t = time.perf_counter()
+            for rec in recs:
+                waited[rec.rid] = (rec, t - submitted[rec.rid])
+        eng.drain()
+    store.close()
+    assert sum(1 for rec, _ in waited.values() if rec.prefetched) > 0
+    assert sum(1 for rec, _ in waited.values() if "decode" in rec.spans) > 0
+    for rec, client_ttft in waited.values():
+        parts = (rec.queue_s + rec.io_s + rec.spans["compute"] + rec.spans["commit"]
+                 + rec.batch_wait_s)
+        assert parts == pytest.approx(client_ttft, abs=1e-3), rec
+        assert min(rec.queue_s, rec.batch_wait_s) >= 0
+        assert rec.ttft_s == pytest.approx(rec.io_s + rec.compute_s)
+        assert {"plan", "compute", "commit", "prefill", "device_to_host",
+                "to_blocks"} <= set(rec.spans)
+    # the TTFT histogram and mean observe what the client waited
+    snap = eng.metrics_snapshot()
+    hist = snap["histograms"]["repro_engine_ttft_seconds"]
+    assert hist["count"] == len(reqs) + len(wl.corpus)
+    client = [w for _, w in waited.values()]
+    assert eng.stats.mean_ttft == pytest.approx(np.mean(client), abs=1e-3)
+    assert snap["gauges"]["repro_engine_mean_ttft_s"] == eng.stats.mean_ttft
+    assert not [k for k in snap["histograms"] if k.startswith("repro_engine_span_seconds_")]
+
+
+def test_real_model_blocks_bit_identical_to_a_float32_copy(smoke_model):
+    """The copy in the cache's own dtype, then the cast, hands back the
+    blocks the copy straight to float32 did."""
+    tokens = list(range(3, 131))
+    blocks, _ = smoke_model(tokens, 32)
+    _, cache = smoke_model._prefill_into(tokens, len(tokens))
+    kv = np.concatenate([np.asarray(cache["k"], np.float32)[:, 0],
+                         np.asarray(cache["v"], np.float32)[:, 0]])
+    rows = kv.transpose(1, 0, 2, 3).reshape(kv.shape[1], -1)
+    want = [rows[i * 16:(i + 1) * 16].astype(np.float16) for i in range(2, 128 // 16)]
+    assert len(blocks) == len(want) == 6
+    for got, exp in zip(blocks, want):
+        assert got.dtype == np.float16
+        np.testing.assert_array_equal(got.view(np.uint16), exp.view(np.uint16))
+
+
+def _host_events(xplane: str):
+    """(line, name, start_ns, end_ns) of every ``repro.*`` event on a host plane."""
+    from jax.profiler import ProfileData
+
+    out = []
+    for plane in ProfileData.from_file(xplane).planes:
+        if plane.name.startswith("/host:"):
+            for i, line in enumerate(plane.lines):
+                out.extend(((plane.name, i), ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+                           for ev in line.events if ev.name.startswith("repro."))
+    return out
+
+
+def _inside(ev, outer):
+    return [o for o in outer if o[0] == ev[0] and o[2] <= ev[2] and ev[3] <= o[3]]
+
+
+def test_program_spans_nest_in_a_cpu_profiler_trace(tmp_path, smoke_model):
+    import glob
+
+    import jax
+
+    smoke_model.warmup(128, 0)
+    h = CacheHierarchy(16, 64, 128, store=KVBlockStore(str(tmp_path / "lsm"), block_size=16))
+    eng = ServingEngine(h, ComputeModel(smoke_model.cfg), kv_bytes_per_token=512,
+                        max_batch_tokens=512, real_prefill=smoke_model)
+    _, reqs = _staged_requests(seed=9, stages=(0.0,))
+    for r in reqs[:3]:
+        eng.submit(r)
+    jax.profiler.start_trace(str(tmp_path / "trace"))
+    try:
+        recs = eng.run()
+    finally:
+        jax.profiler.stop_trace()
+    h.store.close()
+    assert len(recs) == 3
+    (xplane,) = glob.glob(str(tmp_path / "trace" / "**" / "*.xplane.pb"), recursive=True)
+    evs = _host_events(xplane)
+    by = {n: [e for e in evs if e[1] == f"repro.{n}"]
+          for n in ("step", "compute", "prefill", "device_to_host", "to_blocks", "commit")}
+    assert by["step"] and len(by["compute"]) == 3 and len(by["commit"]) == 3
+    for c in by["compute"] + by["commit"]:
+        assert _inside(c, by["step"]), c
+    for n in ("prefill", "device_to_host", "to_blocks"):
+        assert len(by[n]) == 3
+        for e in by[n]:
+            assert _inside(e, by["compute"]), e
