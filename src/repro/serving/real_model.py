@@ -2,8 +2,9 @@
 
 ``RealModel`` holds one model at a config's widths, with weights made from a
 seed, and its jitted prefill and decode on JAX's default device.  Passed as
-the engine's ``real_prefill`` hook it prefills the whole prompt and copies
-the KV blocks past the reused prefix to the host:
+the engine's ``real_prefill`` hook it prefills the whole prompt, lays the
+cache out as float16 rows on the device in the same program, copies the
+rows to the host and cuts out the KV blocks past the reused prefix:
 ``(tokens, reused) -> (blocks, seconds)``.
 
 ``serve_staged`` drives one ``ServingEngine`` with it over a given store:
@@ -17,6 +18,8 @@ it at published widths on a TPU.
 Block layout: block i of a prompt holds tokens [i*B, (i+1)*B) in float16.
 Row t is token t; the columns are (k|v, layer, kv-head, d_head).  The int8
 codec's per-column scales are then per channel over the block's tokens.
+The rows are made on the device (``prefill_rows_fn``); the host only cuts
+the new blocks out of them.
 """
 
 from __future__ import annotations
@@ -98,6 +101,22 @@ class CompileCounter:
         jax.monitoring.unregister_event_listener(self._on_event)
 
 
+def prefill_rows_fn(cfg: ModelConfig):
+    """(params, inputs, cache, pos) -> (logits, rows): the prefill of
+    ``api.prefill_fn``, then its K/V cache laid out as the blocks' rows,
+    ``(S, 2 * L * KVH * Dh)`` float16.  The logits stay an output, so the
+    head (the first token's computation) is part of the program."""
+    prefill = api.prefill_fn(cfg)
+
+    def prefill_rows(params, inputs, cache, pos):
+        logits, cache = prefill(params, inputs, cache, pos)
+        kv = jnp.stack([cache["k"][:, 0], cache["v"][:, 0]])  # (2, L, S, KVH, Dh)
+        rows = jnp.moveaxis(kv, 2, 0).reshape(kv.shape[2], -1)
+        return logits, rows.astype(jnp.float16)
+
+    return prefill_rows
+
+
 class RealModel:
     """A seeded model and its jitted prefill/decode; the engine's
     ``real_prefill`` hook."""
@@ -112,29 +131,30 @@ class RealModel:
         # float32 copy of the largest leaf on the device
         self.params = jax.jit(api.init_params, static_argnums=0)(cfg, jax.random.key(seed))
         self._prefill = jax.jit(api.prefill_fn(cfg))
+        self._prefill_rows = jax.jit(prefill_rows_fn(cfg))
         self._decode = jax.jit(api.decode_fn(cfg))
 
-    def _prefill_into(self, tokens: Sequence[int], max_seq: int):
+    def _prefill_args(self, tokens: Sequence[int], max_seq: int):
         toks = jnp.asarray(np.asarray(tokens, np.int32)[None, :])
-        cache = api.init_cache(self.cfg, 1, max_seq)
-        return self._prefill(self.params, {"tokens": toks}, cache, 0)
+        return self.params, {"tokens": toks}, api.init_cache(self.cfg, 1, max_seq), 0
+
+    def _prefill_into(self, tokens: Sequence[int], max_seq: int):
+        return self._prefill(*self._prefill_args(tokens, max_seq))
 
     def __call__(self, tokens: Sequence[int], reused: int) -> Tuple[List[np.ndarray], float]:
         """Prefill the whole prompt; return the float16 blocks past the
-        ``reused`` prefix and the seconds the prefill took on the device."""
+        ``reused`` prefix and the seconds the prefill and the rows' layout
+        took on the device.  ``reused`` is not part of the program."""
         with maybe_span("prefill"):
             t0 = time.perf_counter()
-            logits, cache = self._prefill_into(tokens, len(tokens))
-            jax.block_until_ready((logits, cache))
+            logits, rows = self._prefill_rows(*self._prefill_args(tokens, len(tokens)))
+            jax.block_until_ready((logits, rows))
             dt = time.perf_counter() - t0
-        with maybe_span("device_to_host"):  # the transfer alone, in the cache's dtype
-            k, v = np.asarray(cache["k"]), np.asarray(cache["v"])
-        with maybe_span("to_blocks"):
+        with maybe_span("device_to_host"):  # one copy of the rows, in float16
+            rows = np.asarray(rows)
+        with maybe_span("to_blocks"):  # each block its own array, not a view of the rows
             B = self.block_size
-            kv = np.concatenate([k.astype(np.float32)[:, 0],
-                                 v.astype(np.float32)[:, 0]])  # (2L, S, KVH, Dh)
-            rows = kv.transpose(1, 0, 2, 3).reshape(kv.shape[1], -1)
-            blocks = [rows[i * B:(i + 1) * B].astype(np.float16)
+            blocks = [rows[i * B:(i + 1) * B].copy()
                       for i in range(reused // B, len(tokens) // B)]
         return blocks, dt
 
@@ -151,10 +171,12 @@ class RealModel:
         return out, logits
 
     def warmup(self, prompt_len: int, decode_tokens: int) -> None:
-        """Compile every program ``serve_staged`` runs at these sizes."""
+        """Compile every program ``serve_staged`` runs at these sizes: the
+        hook's, and with ``decode_tokens`` those of ``generate``."""
         toks = list(range(prompt_len))
         self(toks, 0)
-        self.generate(toks, decode_tokens)
+        if decode_tokens:
+            self.generate(toks, decode_tokens)
 
 
 @dataclass

@@ -16,7 +16,7 @@ from repro.core.codec import CODEC_INT8, CODEC_RAW, BatchCodec, int8_error_bound
 from repro.core.store import KVBlockStore
 from repro.runtime import RuntimeServices
 from repro.serving import ComputeModel, ServingEngine
-from repro.serving.real_model import RealModel, serve_staged
+from repro.serving.real_model import CompileCounter, RealModel, serve_staged
 from repro.workload import PAPER_STAGES, StagedWorkload
 
 
@@ -243,6 +243,38 @@ def test_real_model_blocks_bit_identical_to_a_float32_copy(smoke_model):
     assert len(blocks) == len(want) == 6
     for got, exp in zip(blocks, want):
         assert got.dtype == np.float16
+        np.testing.assert_array_equal(got.view(np.uint16), exp.view(np.uint16))
+
+
+def _float32_copy_blocks(model, tokens, reused):
+    """The host path the device layout replaced: the cache copied in bf16,
+    cast to float32, concatenated, transposed, cut and cast to float16."""
+    _, cache = model._prefill_into(tokens, len(tokens))
+    kv = np.concatenate([np.asarray(cache["k"]).astype(np.float32)[:, 0],
+                         np.asarray(cache["v"]).astype(np.float32)[:, 0]])
+    rows = kv.transpose(1, 0, 2, 3).reshape(kv.shape[1], -1)
+    return [rows[i * 16:(i + 1) * 16].astype(np.float16)
+            for i in range(reused // 16, len(tokens) // 16)]
+
+
+@pytest.mark.parametrize("reused", [0, 32, 40, 128 - 16])
+def test_device_laid_out_blocks_match_the_host_path_and_own_their_data(smoke_model, reused):
+    """Rows laid out on the device give the host path's blocks bit for bit,
+    each an owned C-contiguous float16 array; a new ``reused`` at the same
+    prompt length compiles nothing."""
+    tokens = list(range(5, 133))
+    smoke_model(tokens, 0)
+    with CompileCounter() as counter:
+        blocks, dt = smoke_model(tokens, reused)
+    assert counter.compiles == 0
+    assert dt > 0
+    want = _float32_copy_blocks(smoke_model, tokens, reused)
+    assert len(blocks) == len(want) == 8 - reused // 16
+    cols = 2 * smoke_model.cfg.n_layers * smoke_model.cfg.n_kv_heads * smoke_model.cfg.d_head
+    for got, exp in zip(blocks, want):
+        assert got.dtype == np.float16 and got.shape == (16, cols)
+        assert got.flags.c_contiguous
+        assert got.flags.owndata or got.base.nbytes <= got.nbytes
         np.testing.assert_array_equal(got.view(np.uint16), exp.view(np.uint16))
 
 

@@ -1,8 +1,8 @@
 """Compiles for one TPU v5e chip that is described, not attached: the
 served model's prefill and decode programs at qwen3-14b's published widths,
-and the Pallas kernels of the main path at real widths.  Nothing runs; a
-program the chip's compiler refuses (tiling, fast memory, device memory)
-fails here.
+the serving hook's prefill with its float16 block rows, and the Pallas
+kernels of the main path at real widths.  Nothing runs; a program the
+chip's compiler refuses (tiling, fast memory, device memory) fails here.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU compiler's library, and every test
@@ -20,10 +20,12 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.models import api
+from repro.serving.real_model import prefill_rows_fn
 
 V5E_HBM_BYTES = 16e9
 BLOCK = 16
 PROMPT = 1024
+LONGEST_PROMPT = 2816  # the chat cells' fifth turn
 
 
 @pytest.fixture(scope="module")
@@ -66,6 +68,20 @@ def test_qwen3_prefill_compiles_at_published_widths(one_chip):
     tokens = jax.ShapeDtypeStruct((1, PROMPT), jnp.int32, sharding=one_chip)
     cache = _on(one_chip, api.cache_specs(cfg, 1, PROMPT))
     compiled = jax.jit(api.prefill_fn(cfg)).lower(params, {"tokens": tokens}, cache, 0).compile()
+    assert _device_bytes(compiled) < V5E_HBM_BYTES
+
+
+def test_qwen3_prefill_rows_compiles_for_the_longest_prompt(one_chip):
+    """The engine hook's program at the served depth: the prefill, then its
+    cache laid out on the device as (S, 2 * L * KVH * Dh) float16 rows."""
+    cfg = _qwen3(8)
+    params = _on(one_chip, api.param_specs(cfg))
+    tokens = jax.ShapeDtypeStruct((1, LONGEST_PROMPT), jnp.int32, sharding=one_chip)
+    cache = _on(one_chip, api.cache_specs(cfg, 1, LONGEST_PROMPT))
+    compiled = jax.jit(prefill_rows_fn(cfg)).lower(params, {"tokens": tokens}, cache, 0).compile()
+    _, rows = compiled.out_info
+    cols = 2 * cfg.n_layers * cfg.n_kv_heads * cfg.d_head
+    assert (rows.shape, rows.dtype) == ((LONGEST_PROMPT, cols), jnp.float16)
     assert _device_bytes(compiled) < V5E_HBM_BYTES
 
 
