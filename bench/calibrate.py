@@ -6,10 +6,10 @@
 For each seed, one run of the cell at its own load with a short window,
 and the numbers its check compares.  For the first ``--control-seeds``
 seeds the control too: the reference computed with float8 linear layers
-put in the program's place (``bench/reference.py``) and judged by the
-same check, which has to read it not correct.  Prints one JSON line per
-seed (and appends it to ``--out``).  A limit lies above the largest
-program reading and below the smallest control reading.
+put in the program's place (its family's ``forward``, ``bench/families/``)
+and judged by the same check, which has to read it not correct.  Prints
+one JSON line per seed (and appends it to ``--out``).  A limit lies above
+the largest program reading and below the smallest control reading.
 The benchmark's own runs never run the control.
 """
 

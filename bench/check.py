@@ -1,42 +1,44 @@
 """The comparison that decides ``correct``.
 
-The program hands the engine K/V blocks of 16 tokens in float16: row ``t``
-is token ``t`` and the columns are ``(k|v, layer, kv-head, d_head)``.  The
-reference gives K and V as ``(layers, tokens, kv-heads, d_head)``.  A
-block is compared one layer and one of K/V at a time, by the relative
-error ``|got - ref| / |ref|`` (Frobenius norms over the block's tokens and
-heads), and the worst over all blocks is the number.  A block that should
-be there and is not reads 1.
+The program hands the engine blocks of 16 tokens in float16: row ``t`` is
+token ``t`` and the columns are the family's block parts in turn, each
+over every layer: ``(part, layer, width)`` (``bench/families/``).  The
+reference gives the same rows in float32.  A block is compared one layer
+and one part at a time, by the relative error ``|got - ref| / |ref|``
+(Frobenius norms over the block's tokens and the part's width), and the
+worst over all blocks is the number.  A block that should be there and is
+not, or that has not the row's width, reads 1.
 """
 
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 
-def kv_rows(K: np.ndarray, V: np.ndarray) -> np.ndarray:
-    """K, V ``(L, S, KVH, Dh)`` -> rows ``(S, 2 * L * KVH * Dh)`` in the
-    blocks' column order."""
-    kv = np.concatenate([K, V])
-    return kv.transpose(1, 0, 2, 3).reshape(kv.shape[1], -1)
-
-
 def worst_block_error(got: Sequence[Optional[np.ndarray]], ref_rows: np.ndarray,
-                      first_block: int, layers: int, block: int) -> float:
+                      first_block: int, parts: Sequence[Tuple[str, int]], layers: int,
+                      block: int) -> float:
     """Worst relative error of blocks ``first_block, first_block + 1, ...``
-    of a prompt against the reference rows."""
+    of a prompt against the reference rows; ``parts`` is the family's
+    ``block_parts``."""
     worst = 0.0
     for i, blk in enumerate(got):
         lo = (first_block + i) * block
-        ref = ref_rows[lo:lo + block].reshape(block, 2, layers, -1)
-        if blk is None:
+        ref = ref_rows[lo:lo + block]
+        if blk is None or blk.size != ref.size:
             worst = max(worst, 1.0)
             continue
         diff = blk.astype(np.float32).reshape(ref.shape) - ref
-        err = np.linalg.norm(diff, axis=(0, 3)) / np.linalg.norm(ref, axis=(0, 3))
-        worst = max(worst, float(err.max()))
+        col = 0
+        for _, width in parts:
+            cols = slice(col, col + layers * width)
+            d = diff[:, cols].reshape(block, layers, width)
+            r = ref[:, cols].reshape(block, layers, width)
+            err = np.linalg.norm(d, axis=(0, 2)) / np.linalg.norm(r, axis=(0, 2))
+            worst = max(worst, float(err.max()))
+            col += layers * width
     return worst
 
 

@@ -1,4 +1,5 @@
-"""Operations a prefill requires, from its shapes.
+"""Operations a prefill requires, from its shapes: what every family's
+``prefill_flops`` counts (``bench/families/``).
 
 A prompt of ``S`` tokens whose first ``reused`` tokens are cached requires:
 
@@ -19,18 +20,11 @@ from __future__ import annotations
 from typing import Dict
 
 
-def prefill_flops(cfg: Dict, prompt: int, reused: int) -> float:
-    D = cfg["hidden_size"]
-    H = cfg["num_attention_heads"]
-    KVH = cfg["num_key_value_heads"]
-    Dh = cfg["head_dim"]
-    F = cfg["intermediate_size"]
-    L = cfg["num_hidden_layers"]
-    V = cfg["vocab_size"]
-    new = prompt - reused
-    per_token = 2 * D * (H * Dh + 2 * KVH * Dh) + 2 * H * Dh * D + 2 * 3 * D * F
-    # sum over new positions p in [reused, prompt) of (p + 1) keys
-    keys = prompt * (prompt + 1) // 2 - reused * (reused + 1) // 2
-    attention = 2 * 2 * H * Dh * keys
-    head = 2 * D * V if new > 0 else 0
-    return float(L * (new * per_token + attention) + head)
+def causal_keys(prompt: int, reused: int) -> int:
+    """Keys the new positions ``p`` in ``[reused, prompt)`` attend to: ``p + 1`` each."""
+    return prompt * (prompt + 1) // 2 - reused * (reused + 1) // 2
+
+
+def head(cfg: Dict, new: int) -> int:
+    """The LM head on the last position, where there is a new one."""
+    return 2 * cfg["hidden_size"] * cfg["vocab_size"] if new > 0 else 0

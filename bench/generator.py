@@ -6,12 +6,12 @@ A mix is a set of live *sessions*.  Turn 1 of a session is a shared prefix
 each later turn is the previous prompt, a fresh answer (the engine does
 not decode, so it stands for the answer) and a fresh user message.  After
 ``turns`` turns the session starts again.  A mix of unshared prompts is a
-single session of one turn with no prefix pool.
+single session of one turn with no prefix pool.  Requests arrive on a
+schedule at the cell's rate (``bench/rates/<cell>.json``), whether or not
+earlier ones have been served: the loop is open.
 
 Keys of a mix:
 
-- ``loop``: ``"open"`` (arrivals on a schedule, at the cell's rate) or
-  ``"closed"`` (``clients`` callers, each sending when its last returns);
 - ``prefix_pool``: ``{"count", "tokens", "zipf_s"}``, or null;
 - ``sessions``, ``turns``;
 - ``first_user_tokens``: an int, or a list that new sessions cycle through
@@ -28,11 +28,11 @@ Keys of a mix:
 Steadiness: every seed gets the same work.  The seed makes the content:
 every token, so every prompt and system prompt (and, in the harness, the
 weights).  The shape comes from the mix's ``schedule_seed``, the same for
-every seed: the open-loop arrival times (the quantiles of an exponential
+every seed: the arrival times (the quantiles of an exponential
 at the rate, in a shuffled order), the session each request continues
 (each round of requests visits every live session once), the turn each
 session starts at, and the Zipf rank of each new session's system
-prompt.  An open-loop queue turns a change of arrival order into a change
+prompt.  A queue turns a change of arrival order into a change
 of TTFT far larger than a run's own noise, so that order is not left to
 the seed.
 """
@@ -96,8 +96,6 @@ class Traffic:
         self.answer = tokens(mix.get("answer_tokens", 0))
         self.user = tokens(mix.get("user_tokens", 0))
         self.turns = int(mix["turns"])
-        self.loop = mix["loop"]
-        self.clients = int(mix.get("clients", 0))
         self.lead_in_s = float(mix.get("lead_in_s", 0.0))
         self.min_cached_share = mix.get("min_cached_share")
         self.sessions = [_Session() for _ in range(int(mix["sessions"]))]
@@ -162,7 +160,7 @@ class Traffic:
 
     # ----------------------------------------------------------- arrivals
     def arrivals(self, rate: float, seconds: float) -> List[float]:
-        """Open-loop due offsets in ``[0, seconds)``: ``round(rate *
+        """Due offsets in ``[0, seconds)``: ``round(rate *
         seconds)`` gaps at the exponential's quantiles, in the schedule's
         shuffled order."""
         n = int(round(rate * seconds))

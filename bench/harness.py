@@ -1,6 +1,10 @@
 """One run of one cell: build the served stack, warm it up, drive the
 traffic for the window, check what it served, and reduce the numbers.
 
+The model's family (``bench/families/``, named by the configuration)
+gives the program's config, the block columns, the required FLOPs and the
+reference; nothing here knows one kind of model from another.
+
 The stack is the program's own, built by its public constructors:
 ``ServingEngine`` over ``CacheHierarchy`` over ``ShardedKVBlockStore``,
 with ``RealModel`` as the engine's ``real_prefill`` hook and
@@ -10,10 +14,10 @@ the copy of blocks to the host and keep sampled blocks) and the
 hierarchy's ``fetch``/``fulfill``/``commit`` (spans, and the blocks it
 serves for sampled requests).
 
-TTFT runs from a request's due time (open loop) or send time (closed
-loop) to the return of the ``ServingEngine.step()`` whose records hold
-it.  Prompt tokens per second are those of the requests due in the
-window, over the time from its start until the last of them was served.
+TTFT runs from a request's due time to the return of the
+``ServingEngine.step()`` whose records hold it.  Prompt tokens per second
+are those of the requests due in the window, over the time from its start
+until the last of them was served.
 Set-up is process start to the window's first due request.
 """
 
@@ -33,23 +37,20 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro.cache.hierarchy import CacheHierarchy
-from repro.configs.base import ModelConfig
 from repro.core.sharded_store import ShardedKVBlockStore
 from repro.runtime import RuntimeServices
 from repro.serving.compute_model import ComputeModel
 from repro.serving.engine import ServingEngine
 from repro.serving.real_model import CompileCounter, RealModel, enable_compile_cache
 
-from . import check, flops, generator, reference, spec, stats, trace
+from . import check, generator, spec, stats, trace
 
 GRACE_S = 60.0  # how long requests due in the window are waited for
 SAMPLE = 7  # window requests compared besides one of the longest
 DECODE_TOKENS = 8  # greedy tokens of the decode check
-PROGRAM_EPS = 1e-6  # the program's RMSNorm epsilon
 
-# the CPU rehearsal: every width cut to a smoke size, token counts by 16
-REHEARSAL_WIDTHS = dict(hidden_size=64, num_attention_heads=4, head_dim=16,
-                        intermediate_size=128, vocab_size=256, num_hidden_layers=2)
+# the CPU rehearsal: the family cuts every width to a smoke size, and token
+# counts are cut by 16
 REHEARSAL_TOKEN_SCALE = 16
 
 
@@ -62,9 +63,9 @@ def prompt_key(tokens) -> int:
 
 
 def rehearsal_config(cfg: Dict) -> Dict:
-    out = dict(cfg, **REHEARSAL_WIDTHS)
-    out["num_key_value_heads"] = min(2, cfg["num_key_value_heads"])
-    out["as_run"] = dict(cfg["as_run"], rope_dims=out["head_dim"])
+    """The configuration at its family's smoke widths, with the memory
+    tiers cut as the token counts are."""
+    out = spec.family(cfg).rehearsal_config(cfg)
     dep = dict(cfg["deployment"])
     for k in ("device_blocks", "host_blocks"):
         dep[k] = max(4, dep[k] // REHEARSAL_TOKEN_SCALE)
@@ -72,24 +73,14 @@ def rehearsal_config(cfg: Dict) -> Dict:
     return out
 
 
-def model_config(cfg: Dict) -> ModelConfig:
-    run = cfg["as_run"]
-    if run["rms_norm_eps"] != PROGRAM_EPS or run["rope_dims"] != cfg["head_dim"]:
-        raise ValueError(f"{cfg['name']}: the program runs RMSNorm eps {PROGRAM_EPS} and RoPE "
-                         "over the whole head; the configuration asks otherwise")
-    return ModelConfig(
-        name=cfg["name"], family="dense", n_layers=cfg["num_hidden_layers"],
-        d_model=cfg["hidden_size"], n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"], d_ff=cfg["intermediate_size"],
-        vocab_size=cfg["vocab_size"], d_head=cfg["head_dim"], qk_norm=bool(run["qk_norm"]),
-        qkv_bias=bool(run["qkv_bias"]), rope_theta=float(run["rope_theta"]))
-
-
 # ------------------------------------------------------------ sampling
 @dataclass
 class Picked:
     tokens: List[int]
-    served: List[np.ndarray]  # blocks the hierarchy handed over, from block 0
+    # blocks the hierarchy handed over, from block 0; in a mix that shares
+    # nothing, those the store returns after the window, None for each
+    # block of the prompt it does not return
+    served: List[Optional[np.ndarray]]
     prefill: Optional[List[np.ndarray]] = None  # blocks the hook returned
     reused: int = 0
 
@@ -192,6 +183,9 @@ class Window:
     fulfills: List[Tuple[float, float, int]]
     required_flops: float  # of the hook calls inside the window
     peaks: Dict
+    # the family's (flops, bytes) of each named scope, over the same calls;
+    # the trace's ``scope_s`` holds their device time
+    scope_costs: Dict[str, Tuple[float, float]] = field(default_factory=dict)
     reduction: Optional[trace.Reduction] = None
 
     def delta(self, name: str) -> float:
@@ -210,7 +204,7 @@ def counters(eng: ServingEngine, store) -> Dict[str, float]:
 
 @dataclass
 class Served:
-    due: Dict[int, float] = field(default_factory=dict)  # rid -> due or send time
+    due: Dict[int, float] = field(default_factory=dict)  # rid -> due time
     sent: Dict[int, float] = field(default_factory=dict)
     done: Dict[int, Tuple[object, float]] = field(default_factory=dict)
     prompts: Dict[int, generator.Prompt] = field(default_factory=dict)
@@ -225,11 +219,9 @@ def drive(eng, traffic, rate, t_lead, t_win, seconds, sampler, on_start, on_end)
     sv = Served()
     started = ended = False
     outstanding = 0
-    open_loop = traffic.loop == "open"
-    if open_loop:
-        sched = [(t_lead + o, False) for o in traffic.arrivals(rate, t_win - t_lead)]
-        sched += [(t_win + o, True) for o in traffic.arrivals(rate, seconds)]
-        queue = [(due, traffic.next(), w) for due, w in sched]
+    sched = [(t_lead + o, False) for o in traffic.arrivals(rate, t_win - t_lead)]
+    sched += [(t_win + o, True) for o in traffic.arrivals(rate, seconds)]
+    queue = [(due, traffic.next(), w) for due, w in sched]
     i = 0
 
     def submit(p, due, now, in_window):
@@ -241,10 +233,6 @@ def drive(eng, traffic, rate, t_lead, t_win, seconds, sampler, on_start, on_end)
         eng.submit(p)
         outstanding += 1
 
-    if not open_loop:
-        now = time.perf_counter()
-        for _ in range(traffic.clients):
-            submit(traffic.next(), now, now, now >= t_win)
     while True:
         now = time.perf_counter()
         if not started and now >= t_win:
@@ -255,11 +243,10 @@ def drive(eng, traffic, rate, t_lead, t_win, seconds, sampler, on_start, on_end)
             ended = True
         if ended and (now >= t_end + GRACE_S or all(r in sv.done for r in sv.window)):
             break
-        if open_loop:
-            while i < len(queue) and queue[i][0] <= now:
-                due, p, w = queue[i]
-                submit(p, due, now, w)
-                i += 1
+        while i < len(queue) and queue[i][0] <= now:
+            due, p, w = queue[i]
+            submit(p, due, now, w)
+            i += 1
         if outstanding:
             with TraceAnnotation("bench.engine_step"):
                 recs = eng.step()
@@ -267,10 +254,8 @@ def drive(eng, traffic, rate, t_lead, t_win, seconds, sampler, on_start, on_end)
             for r in recs:
                 sv.done[r.rid] = (r, t)
                 outstanding -= 1
-                if not open_loop and t < t_end:
-                    submit(traffic.next(), t, t, t >= t_win)
             continue
-        nxt = [t_win, t_end] + ([queue[i][0]] if open_loop and i < len(queue) else [])
+        nxt = [t_win, t_end] + ([queue[i][0]] if i < len(queue) else [])
         nxt = [x for x in nxt if x > now]
         if not nxt:
             break
@@ -308,18 +293,19 @@ def run(cfg: Dict, mix: Dict, limits: Dict, seed: int, seconds: float, rate: Opt
     program's place, beside it: the same check, the same limits."""
     if rehearse:
         cfg = rehearsal_config(cfg)
+    fam = spec.family(cfg)
     scale = REHEARSAL_TOKEN_SCALE if rehearse else 1
     dev = jax.devices()[0]
     peaks = {} if rehearse else spec.peaks(dev.device_kind)
     dep = cfg["deployment"]
     B = dep["block_tokens"]
-    mcfg = model_config(cfg)
+    mcfg = fam.model_config(cfg)
     traffic = generator.Traffic(mix, cfg["vocab_size"], B, seed, scale=scale)
-    if traffic.loop == "open" and not rate:
-        raise ValueError("an open-loop mix needs a rate")
+    if not rate:
+        raise ValueError("the traffic needs a rate")
     lengths = traffic.lengths()
     log(f"cell: {cfg['name']} ({cfg['num_hidden_layers']} layers, d_model {cfg['hidden_size']}), "
-        f"{traffic.loop} loop, rate {rate} req/s, clients {traffic.clients}, prompt lengths "
+        f"rate {rate} req/s, prompt lengths "
         f"{lengths}, seed {seed}, window {seconds} s, device {dev.platform} {dev.device_kind!r}")
     cache_dir = enable_compile_cache()
     store_dir = tempfile.mkdtemp(prefix="kvbench_store_")
@@ -419,18 +405,28 @@ def run(cfg: Dict, mix: Dict, limits: Dict, seed: int, seconds: float, rate: Opt
         "compiles_in_window": counter.compiles,
         "writebehind_backlog_at_window_end": win.get("backlog"),
         "store_disk_bytes_at_window_end": win.get("disk_bytes"),
+        # what the run wrote to the store, set-up included: a disk that a
+        # check's many runs share fills with it
+        "store_bytes_written": store.stats.payload_bytes_stored,
         "set_up_compiles": setup_compiles.compiles,
     }
+    calls = [c for c in hook.calls if t_win <= c[0] < t_end]
+    scope_costs: Dict[str, Tuple[float, float]] = {}
+    if hasattr(fam, "scope_costs"):
+        for c in calls:
+            for sc, (f, b) in fam.scope_costs(cfg, c[3], c[4]).items():
+                f0, b0 = scope_costs.get(sc, (0.0, 0.0))
+                scope_costs[sc] = (f0 + f, b0 + b)
     window = Window(
         seconds=seconds, start=t_win, end=t_end, records=recs, ttft=ttft,
         counters_before=win.get("before", {}), counters_after=win.get("after", {}),
-        hook_calls=[c for c in hook.calls if t_win <= c[0] < t_end],
-        fulfills=[f for f in h.fulfills if t_win <= f[0] < t_end],
-        required_flops=sum(flops.prefill_flops(cfg, c[3], c[4]) for c in hook.calls
-                           if t_win <= c[0] < t_end),
-        peaks=peaks)
+        hook_calls=calls, fulfills=[f for f in h.fulfills if t_win <= f[0] < t_end],
+        required_flops=sum(fam.prefill_flops(cfg, c[3], c[4]) for c in calls),
+        peaks=peaks, scope_costs=scope_costs)
+    stamps["required_flops"] = window.required_flops
     if profiling:
-        window.reduction = trace.reduce(trace.extract(trace.find_xplane(trace_dir)))
+        window.reduction = trace.reduce(trace.extract(trace.find_xplane(trace_dir)),
+                                        scopes=sorted(scope_costs))
 
     # ---- the program's decode of the longest sampled prompt, then free it
     picked = sampler.picked()
@@ -445,6 +441,16 @@ def run(cfg: Dict, mix: Dict, limits: Dict, seed: int, seconds: float, rate: Opt
         hit = sum(window.delta(f"repro_cache_tokens_hit_{t}") for t in ("device", "host", "disk"))
         share = hit / req if req else None
     over = sum(1 for r in recs if r.reused_tokens > sv.prompts[r.rid].shared)
+    shares = mix.get("min_cached_share") is not None
+    if not shares:
+        # nothing is served from the cache in a mix that shares nothing: what
+        # the KV tier gives back is what the store returns for the sampled
+        # prompts once the write-behind has drained.  Every block of every
+        # prompt was committed, so each one it does not return reads 1.
+        for p in picked:
+            n = store.probe(p.tokens)
+            got = store.get_batch(p.tokens, n) if n else []
+            p.served = list(got) + [None] * (len(p.tokens) // B - len(got))
     runtime.close()
     store.close()
     shutil.rmtree(store_dir, ignore_errors=True)
@@ -453,41 +459,40 @@ def run(cfg: Dict, mix: Dict, limits: Dict, seed: int, seconds: float, rate: Opt
 
     # ---- the reference, and the control where asked
     numbers: Dict[str, float] = {"unanswered_requests": failed, "over_reused_requests": over}
-    if mix.get("min_cached_share") is not None:
+    if shares:
         numbers["cached_share"] = share
     ctrl: Dict[str, float] = {}
-    rc = reference.RefConfig.from_file(cfg)
-    L = cfg["num_hidden_layers"]
-    w = reference.init_weights(rc, seed)
+    parts, L = fam.block_parts(cfg), cfg["num_hidden_layers"]
+    w = fam.init_weights(cfg, seed)
     t_ref = time.perf_counter()
     pre_err, srv_err, c_pre, c_srv = 0.0, None, 0.0, None
     for p in picked:
-        K, V, _ = reference.forward(rc, w, p.tokens)
-        rows = check.kv_rows(K, V)
+        rows, _ = fam.forward(cfg, w, p.tokens)
         first = p.reused // B
         expect = len(p.tokens) // B - first
         got = list(p.prefill or [])[:expect]
         got += [None] * (expect - len(got))
-        pre_err = max(pre_err, check.worst_block_error(got, rows, first, L, B))
+        pre_err = max(pre_err, check.worst_block_error(got, rows, first, parts, L, B))
         if p.served:
-            srv_err = max(srv_err or 0.0, check.worst_block_error(p.served, rows, 0, L, B))
+            srv_err = max(srv_err or 0.0,
+                          check.worst_block_error(p.served, rows, 0, parts, L, B))
         if control:
-            cK, cV, _ = reference.forward(rc, w, p.tokens, fp8=True)
-            crows = check.kv_rows(cK, cV).astype(np.float16)
+            crows = fam.forward(cfg, w, p.tokens, fp8=True)[0].astype(np.float16)
             cblocks = [crows[i * B:(i + 1) * B] for i in range(len(p.tokens) // B)]
-            c_pre = max(c_pre, check.worst_block_error(cblocks[first:], rows, first, L, B))
+            c_pre = max(c_pre, check.worst_block_error(cblocks[first:], rows, first, parts, L, B))
             if p.served:
                 c_srv = max(c_srv or 0.0, check.worst_block_error(
-                    [check.int8_round_trip(b) for b in cblocks[:len(p.served)]], rows, 0, L, B))
+                    [check.int8_round_trip(b) for b in cblocks[:len(p.served)]], rows, 0,
+                    parts, L, B))
     numbers["kv_prefill_rel_err"] = pre_err if picked else None
     if srv_err is not None:
         numbers["kv_served_rel_err"] = srv_err
     if gen_prompt is not None:
         seq = list(gen_prompt) + list(gen_toks)
-        _, _, ref_logits = reference.forward(rc, w, seq, head_rows=[len(seq) - 1])
+        _, ref_logits = fam.forward(cfg, w, seq, head_rows=[len(seq) - 1])
         numbers["decode_logits_rel_err"] = check.relative_error(gen_logits, ref_logits)
         if control:
-            _, _, c_logits = reference.forward(rc, w, seq, head_rows=[len(seq) - 1], fp8=True)
+            _, c_logits = fam.forward(cfg, w, seq, head_rows=[len(seq) - 1], fp8=True)
             ctrl["decode_logits_rel_err"] = check.relative_error(c_logits, ref_logits)
     if control:
         ctrl["kv_prefill_rel_err"] = c_pre
@@ -498,14 +503,13 @@ def run(cfg: Dict, mix: Dict, limits: Dict, seed: int, seconds: float, rate: Opt
     stamps["reference_s"] = time.perf_counter() - t_ref
     stamps["compared_requests"] = len(picked)
     stamps["compared_blocks"] = sum(len(p.tokens) // B for p in picked)
+    stamps["compared_served_blocks"] = sum(b is not None for p in picked for b in p.served)
 
     lims = dict(limits)
     lims["unanswered_requests"] = {"max": 0}
     lims["over_reused_requests"] = {"max": 0}
-    if mix.get("min_cached_share") is not None:
+    if shares:
         lims["cached_share"] = {"min": mix["min_cached_share"]}
-    else:
-        lims.pop("kv_served_rel_err", None)
     compared = check.verdict(numbers, lims)
     control_compared = check.verdict(dict(numbers, **ctrl), lims) if control else []
     device = {"platform": dev.platform, "kind": dev.device_kind, "count": len(jax.devices()),

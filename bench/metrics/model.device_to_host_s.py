@@ -1,5 +1,5 @@
-"""Mean seconds of the copy of the K/V cache from the device to the host, in
-its device dtype: the program's ``device_to_host`` span in
+"""Mean seconds of the copy of the prompt's float16 block rows from the
+device to the host: the program's ``device_to_host`` span in
 ``RealModel.__call__`` (``RequestRecord.spans``), over the window's requests
 that ran a prefill.  None where the program records no such span.
 """

@@ -11,8 +11,8 @@ where JAX finds no TPU or fewer chips than the cell asks for.
 
 ``--rehearse`` runs on the CPU at smoke widths (every width and token
 count cut) and prints a line with no metric and no device: it exercises
-the path, not the chip.  ``--rate`` offers an open-loop cell's traffic at
-another rate, as a knee sweep does.
+the path, not the chip.  ``--rate`` offers a cell's traffic at another
+rate, as a knee sweep does.
 """
 
 import time

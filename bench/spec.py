@@ -7,7 +7,13 @@ under ``bench/`` that a later change adds without editing any other.
 - ``bench/limits/<config>.json``: the limits ``correct`` holds the
   configuration's outputs to;
 - ``bench/metrics/<metric>.py``: the reader of one per-layer metric, a
-  function ``read(window)`` returning a number or None;
+  function ``read(window)`` returning a number or None.  A metric that
+  reads the same in other cells, under a name of its own so that it can
+  move another end-to-end metric (``<base>.<tag>``), has no file of its
+  own and is read by ``<base>.py``;
+- ``bench/families/<family>.py``: what the harness knows of one kind of
+  model (the program's config, block columns, FLOPs, the reference), named
+  by a configuration's ``bench_family`` key, ``gqa`` where it has none;
 - ``bench/peaks.json``: the chip's peaks, keyed by ``device_kind``.
 """
 
@@ -15,6 +21,7 @@ from __future__ import annotations
 
 import importlib.util
 import json
+import sys
 from pathlib import Path
 from typing import Callable, Dict, List, Optional
 
@@ -62,12 +69,30 @@ def peaks(device_kind: str) -> Dict:
     return table[device_kind]
 
 
-def reader(metric: str) -> Callable:
-    path = BENCH / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{metric}", path)
+def _load(path: Path, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def reader(metric: str) -> Callable:
+    path = BENCH / "metrics" / f"{metric}.py"
+    base = BENCH / "metrics" / f"{metric.rsplit('.', 1)[0]}.py"
+    if not path.exists() and base.exists():
+        path = base
+    return _load(path, f"bench_metric_{metric}").read
+
+
+def family(cfg: Dict):
+    """The family module of a configuration file's dict.  Loaded once per
+    file, so that what it compiles is compiled once per process."""
+    path = BENCH / "families" / f"{cfg.get('bench_family', 'gqa')}.py"
+    name = f"bench_family_{path.stem}"
+    mod = sys.modules.get(name)
+    if mod is None or mod.__file__ != str(path):
+        mod = sys.modules[name] = _load(path, name)
+    return mod
 
 
 def metrics_of(cell_name: str, kind: str) -> List[Dict]:

@@ -4,14 +4,21 @@
 ``jax.profiler.ProfileData``: on every ``/device:TPU:<n>`` plane the ``XLA
 Ops`` line (one event per operation run on the chip) and the ``XLA Modules``
 line (one event per program run), and on the host planes the benchmark's
-own ``bench.*`` spans (``jax.profiler.TraceAnnotation``).  ``reduce`` then
-works on those plain events, so a recorded cut of a trace tests it.
+own ``bench.*`` spans (``jax.profiler.TraceAnnotation``).  Each operation
+also keeps its ``jax.named_scope`` path: the ``tf_op`` stat of its event
+metadata (``jit(prefill_rows)/attention/dot_general:``), which
+``ProfileData`` does not show and ``op_scopes`` reads from the file's
+protobuf.  ``reduce`` then works on those plain events, so a recorded cut
+of a trace tests it.
 
 - busy: the union of the operation intervals of a device inside the
   window, averaged over the devices; idle share is one minus busy over the
   window.  The window is the ``bench.window`` span.
 - program time: the summed durations of the program runs whose name holds
   a given part (``prefill``).
+- scope time: the summed durations of the operations inside a run of
+  the prefill program whose scope path holds a given scope as a whole
+  component (``attention`` in ``.../attention/dot_general:``).
 - top operations: device seconds summed by operation name (its first
   ``OP_NAME_CHARS`` characters).
 - idle gaps: each gap between busy intervals is named by the innermost
@@ -21,6 +28,7 @@ works on those plain events, so a recorded cut of a trace tests it.
 
 from __future__ import annotations
 
+import bisect
 import glob
 import json
 import os
@@ -28,6 +36,9 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
 Event = Tuple[str, float, float]  # name, start_s, duration_s
+# an operation: name, start_s, duration_s and its scope path ("" where it has
+# none; a cut recorded before operations kept it holds the first three alone)
+OpEvent = Tuple[str, float, float, str]
 
 WINDOW_SPAN = "bench.window"
 SPAN_PREFIX = "bench."
@@ -36,11 +47,12 @@ OFF_THREAD_SPANS = ("bench.fetch",)
 # an operation is named by the head of its HLO text (name, first shapes);
 # the whole text runs to thousands of characters
 OP_NAME_CHARS = 100
+SCOPE_STAT = "tf_op"  # the event metadata's stat that holds the scope path
 
 
 @dataclass
 class TraceEvents:
-    ops: Dict[str, List[Event]] = field(default_factory=dict)  # by device plane
+    ops: Dict[str, List[OpEvent]] = field(default_factory=dict)  # by device plane
     modules: Dict[str, List[Event]] = field(default_factory=dict)
     spans: List[Event] = field(default_factory=list)
 
@@ -60,6 +72,7 @@ class Reduction:
     busy_s: float  # averaged over devices
     devices: int
     program_s: Dict[str, float]
+    scope_s: Dict[str, float]  # of the prefill program's operations, by scope
     top_ops: List[Tuple[str, float]]
     idle_gaps: List[Tuple[str, float]]
 
@@ -75,16 +88,97 @@ def find_xplane(trace_dir: str) -> str:
     return found[0]
 
 
+def _varint(buf: bytes, i: int) -> Tuple[int, int]:
+    x = shift = 0
+    while True:
+        b = buf[i]
+        i += 1
+        x |= (b & 0x7F) << shift
+        if b < 0x80:
+            return x, i
+        shift += 7
+
+
+def _fields(buf: bytes, lo: int, hi: int):
+    """``(number, value)`` of each field of the protobuf message in
+    ``buf[lo:hi]``: an int for a varint, ``(start, end)`` for the rest."""
+    i = lo
+    while i < hi:
+        key, i = _varint(buf, i)
+        wire = key & 7
+        if wire == 0:
+            val, i = _varint(buf, i)
+        elif wire == 2:
+            n, i = _varint(buf, i)
+            val, i = (i, i + n), i + n
+        elif wire in (1, 5):
+            n = 8 if wire == 1 else 4
+            val, i = (i, i + n), i + n
+        else:
+            raise ValueError(f"protobuf wire type {wire} at byte {i}")
+        yield key >> 3, val
+
+
+def op_scopes(path: str) -> Dict[str, Dict[str, str]]:
+    """Scope path of each operation, by device plane and operation name,
+    from the ``XSpace`` protobuf (``tsl/profiler/protobuf/xplane.proto``):
+    ``XSpace.planes`` (1) -> ``XPlane.name`` (2), ``event_metadata`` (4:
+    ``XEventMetadata`` name 2, stats 5) and ``stat_metadata`` (5:
+    ``XStatMetadata`` id 1, name 2); an ``XStat`` holds its metadata id (1)
+    and a string (5) or a reference to a stat metadata's name (7).  Lines,
+    the bulk of the file, are skipped unread."""
+    with open(path, "rb") as f:
+        buf = f.read()
+    text = lambda v: buf[v[0]:v[1]].decode(errors="replace")  # noqa: E731
+    out: Dict[str, Dict[str, str]] = {}
+    for num, plane in _fields(buf, 0, len(buf)):
+        if num != 1:
+            continue
+        name, events, stat_names = "", [], {}
+        for n, v in _fields(buf, *plane):
+            if n == 2:
+                name = text(v)
+            elif n == 4:
+                events.append(v)
+            elif n == 5:
+                meta = dict(f for k, entry in _fields(buf, *v) if k == 2
+                            for f in _fields(buf, *entry))
+                stat_names[meta.get(1, 0)] = text(meta[2]) if 2 in meta else ""
+        if not name.startswith("/device:TPU:"):
+            continue
+        scopes = out.setdefault(name, {})
+        for entry in events:
+            for k, meta in _fields(buf, *entry):
+                if k != 2:
+                    continue
+                op, scope = "", ""
+                for n, v in _fields(buf, *meta):
+                    if n == 2:
+                        op = text(v)
+                    elif n == 5:
+                        stat = dict(_fields(buf, *v))
+                        if stat_names.get(stat.get(1)) == SCOPE_STAT:
+                            scope = text(stat[5]) if 5 in stat else stat_names.get(stat.get(7), "")
+                if scope:
+                    scopes[op] = scope
+    return out
+
+
 def extract(path: str) -> TraceEvents:
     from jax.profiler import ProfileData
 
     out = TraceEvents()
+    scopes = op_scopes(path)
     for plane in ProfileData.from_file(path).planes:
         if plane.name.startswith("/device:TPU:"):
+            where = scopes.get(plane.name, {})
             for line in plane.lines:
-                dest = {"XLA Ops": out.ops, "XLA Modules": out.modules}.get(line.name)
-                if dest is not None:
-                    dest.setdefault(plane.name, []).extend(
+                if line.name == "XLA Ops":
+                    out.ops.setdefault(plane.name, []).extend(
+                        (ev.name, ev.start_ns * 1e-9, ev.duration_ns * 1e-9,
+                         where.get(ev.name, "")) for ev in line.events)
+                elif line.name == "XLA Modules":
+                    out.modules.setdefault(plane.name, []).extend(
                         (ev.name, ev.start_ns * 1e-9, ev.duration_ns * 1e-9)
                         for ev in line.events)
         elif plane.name.startswith("/host:"):
@@ -119,7 +213,14 @@ def _innermost(spans: Sequence[Event], t: float) -> str:
     return best[0] if best else "host"
 
 
-def reduce(ev: TraceEvents, programs: Sequence[str] = ("prefill",), top: int = 10) -> Reduction:
+def _in_runs(runs: Sequence[Tuple[float, float]], t: float) -> bool:
+    """Whether ``t`` lies in one of the sorted, disjoint ``runs``."""
+    i = bisect.bisect_right(runs, (t, float("inf"))) - 1
+    return i >= 0 and t < runs[i][1]
+
+
+def reduce(ev: TraceEvents, programs: Sequence[str] = ("prefill",), scopes: Sequence[str] = (),
+           top: int = 10) -> Reduction:
     windows = [(s, s + d) for n, s, d in ev.spans if n == WINDOW_SPAN]
     if len(windows) != 1:
         raise ValueError(f"{len(windows)} {WINDOW_SPAN} spans in the trace")
@@ -128,13 +229,21 @@ def reduce(ev: TraceEvents, programs: Sequence[str] = ("prefill",), top: int = 1
     busy_total = 0.0
     op_s: Dict[str, float] = {}
     gaps: Dict[str, float] = {}
+    scope_s = {sc: 0.0 for sc in scopes}
     for plane, ops in ev.ops.items():
-        busy = _union(_clip([(s, s + d) for _, s, d in ops], lo, hi))
+        busy = _union(_clip([(s, s + d) for _, s, d, *_ in ops], lo, hi))
         busy_total += sum(b - a for a, b in busy)
-        for name, s, d in ops:
+        runs = sorted((s, s + d) for name, s, d in ev.modules.get(plane, [])
+                      if "prefill" in name and lo <= s < hi)
+        for name, s, d, *path in ops:
             if s < hi and s + d > lo:
                 name = name[:OP_NAME_CHARS]
                 op_s[name] = op_s.get(name, 0.0) + d
+            if scopes and path and path[0] and _in_runs(runs, s):
+                parts = path[0].split("/")
+                for sc in scopes:
+                    if sc in parts:
+                        scope_s[sc] += d
         edges = [lo] + [x for ab in busy for x in ab] + [hi]
         for a, b in zip(edges[0::2], edges[1::2]):
             if b > a:
@@ -152,6 +261,7 @@ def reduce(ev: TraceEvents, programs: Sequence[str] = ("prefill",), top: int = 1
         busy_s=busy_total / n,
         devices=len(ev.ops),
         program_s=program_s,
+        scope_s={sc: v / n for sc, v in scope_s.items()},
         top_ops=sorted(((k, v / n) for k, v in op_s.items()), key=lambda kv: -kv[1])[:top],
         idle_gaps=sorted(((k, v / n) for k, v in gaps.items()), key=lambda kv: -kv[1])[:top],
     )

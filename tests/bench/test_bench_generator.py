@@ -1,5 +1,5 @@
 """The traffic generator: determinism, the designed shapes of the mixes,
-and the open- and closed-loop schedules."""
+and the arrival schedule."""
 
 import collections
 
@@ -76,11 +76,9 @@ def test_every_seed_gets_the_same_work():
     assert [len(c.next().tokens) for _ in range(150)] != [len(p.tokens) for p in pa]
 
 
-def test_unshared_closed_loop_two_clients_nothing_shared():
-    mix = spec.traffic("unshared")
-    assert mix["loop"] == "closed"
-    t = generator.Traffic(mix, VOCAB, BLOCK, 5)
-    assert t.clients == 2 and t.warmup() == []
+def test_unshared_every_length_equally_nothing_shared():
+    t = generator.Traffic(spec.traffic("unshared"), VOCAB, BLOCK, 5)
+    assert t.warmup() == [] and t.lengths() == _chat(5).lengths()
     prompts = [t.next() for _ in range(50)]
     assert collections.Counter(len(p.tokens) for p in prompts) == {
         n: 10 for n in (768, 1280, 1792, 2304, 2816)}

@@ -33,15 +33,14 @@ def test_benchmark_json_shape():
         assert w["config"] in configs and w["chips"] in (1, 4) and len(w["why"]) <= 200
         assert (spec.BENCH / "traffic" / f"{w['traffic']}.json").exists()
         assert (spec.BENCH / "limits" / f"{w['config']}.json").exists()
-        if spec.traffic(w["traffic"])["loop"] == "open":
-            assert spec.rate(w["name"]) > 0
+        assert spec.rate(w["name"]) > 0
     for m in b["end_to_end"]:
         assert 0.01 <= m["bound"] <= 0.25 and m["source"] in ("host_clock", "device_trace")
     for m in b["end_to_end"] + b["per_layer"]:
         assert NAME.match(m["name"]) and UNIT.match(m["unit"]) and m["better"] in ("lower", "higher")
     for m in b["per_layer"]:
         assert m["moves"] in e2e and set(m["workloads"]) <= set(cells)
-        assert (spec.BENCH / "metrics" / f"{m['name']}.py").exists()
+        assert callable(spec.reader(m["name"]))
     for n in list(cells) + list(configs):
         assert NAME.match(n)
 
@@ -73,6 +72,18 @@ def test_found_by_name(tmp_path, monkeypatch):
     assert spec.rate("new-model.new-mix") == 1.5
     t = generator.Traffic(spec.traffic("new-mix"), 1000, 16, 0)
     assert len(t.sessions) == 8
+
+
+def test_a_tagged_metric_is_read_by_its_base_unless_it_has_a_file(tmp_path, monkeypatch):
+    (tmp_path / "metrics").mkdir()
+    (tmp_path / "metrics" / "layer.thing_s.py").write_text("def read(w):\n    return 1.0\n")
+    (tmp_path / "metrics" / "layer.thing_s.own.py").write_text("def read(w):\n    return 2.0\n")
+    monkeypatch.setattr(spec, "BENCH", tmp_path)
+    assert spec.reader("layer.thing_s")(None) == 1.0
+    assert spec.reader("layer.thing_s.tail")(None) == 1.0
+    assert spec.reader("layer.thing_s.own")(None) == 2.0
+    with pytest.raises(FileNotFoundError):
+        spec.reader("layer.other_s")
 
 
 def test_peaks_by_device_kind():

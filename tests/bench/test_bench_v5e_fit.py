@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from bench import generator, harness, reference, spec
+from bench import generator, harness, spec
 from repro.models import api
 
 HBM_BYTES = spec.peaks("TPU v5 lite")["hbm_bytes"]
@@ -55,7 +55,8 @@ def _bytes(compiled):
 
 @pytest.mark.parametrize("name", [c["name"] for c in spec.benchmark()["configs"]])
 def test_largest_prefill_fits_one_chip(name, one_chip):
-    mcfg = harness.model_config(spec.config(name))
+    cfg = spec.config(name)
+    mcfg = spec.family(cfg).model_config(cfg)
     longest = _longest(name)
     on = lambda tree: jax.tree.map(  # noqa: E731
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), tree)
@@ -70,12 +71,8 @@ def test_largest_prefill_fits_one_chip(name, one_chip):
 @pytest.mark.parametrize("name", [c["name"] for c in spec.benchmark()["configs"]])
 def test_reference_of_the_longest_decode_fits_one_chip(name, fp8, one_chip):
     """The check runs the reference on the chip once the program is freed,
-    over the longest prompt and its decoded tokens."""
-    rc = reference.RefConfig.from_file(spec.config(name))
-    w = {n: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
-         for n, s, _ in reference.leaf_specs(rc)}
+    over the longest prompt and its decoded tokens, as its family builds it."""
+    cfg = spec.config(name)
     seq = _longest(name) + harness.DECODE_TOKENS
-    toks = jax.ShapeDtypeStruct((seq,), jnp.int32, sharding=one_chip)
-    rows = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
-    compiled = reference._forward.lower(rc, w, toks, rows, fp8).compile()
+    compiled = spec.family(cfg).lower_reference(cfg, seq, fp8, one_chip).compile()
     assert _bytes(compiled) < HBM_BYTES, f"{name}: {_bytes(compiled)} B for {seq} tokens"
